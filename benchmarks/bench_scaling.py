@@ -20,8 +20,8 @@ The batched-serving series sweeps the micro-batch width B over one
 modal-bucket pool: B same-bucket graphs per ``solve_batch`` call run as
 ONE fused device program (DESIGN.md §8), so circuits/s rises with B as
 per-program dispatch, collective-rendezvous, and host-sync overheads
-amortize — the acceptance target is B=8 ≥ 2× B=1 even on the CPU
-interpret-mode mesh.  On a 2-core host the sequential baseline is
+amortize — the acceptance target is B=8 ≥ 2× B=1 even on the
+simulated CPU mesh.  On a 2-core host the sequential baseline is
 dispatch-noise-limited (observed B=8/B=1 ratios 1.9–2.9× across
 processes, ≈2.0–2.4× typical); beefier hosts amortize more, since the
 batched program's wider ops also gain intra-op parallelism the tiny
@@ -446,7 +446,7 @@ def run_phase3(series=PHASE3_SERIES, seed=0, repeats=3):
     the O(2E) → O(2E/n) memory claim the sharding buys.  Circuits are
     asserted byte-identical across the modes before timing is reported.
     """
-    from repro.analysis.jaxpr_audit import pallas_cost_model
+    from repro.analysis.jaxpr_audit import phase3_cost_model
 
     rows = []
     for scale, parts in series:
@@ -470,8 +470,8 @@ def run_phase3(series=PHASE3_SERIES, seed=0, repeats=3):
         assert np.array_equal(r_rep.circuit, r_sh.circuit)
         assert np.array_equal(r_rep.circuit, r_ng.circuit)
         e_cap = r_sh.cache.bucket[0]
-        rep_cost = pallas_cost_model(e_cap, None)
-        sh_cost = pallas_cost_model(e_cap, None, n_parts=parts,
+        rep_cost = phase3_cost_model(e_cap, None)
+        sh_cost = phase3_cost_model(e_cap, None, n_parts=parts,
                                     sharded=True)
         rows.append({
             "graph": f"s{scale}/P{parts}",

@@ -12,7 +12,8 @@ costed *compositionally* — a one-layer program (full attention, no remat,
 dense xent) is lowered on the same mesh and scaled by L, then embed/head +
 optimizer programs are added.  GNN/recsys cells contain no scans (direct).
 The Euler superstep is re-lowered in static-rounds analysis mode so every
-hook/splice round is visible.  The dominant term and the 6·N·D
+hook/splice round is visible; sorts and scans longer than
+``repro.core.bounded.NATIVE_MAX`` stay loops and count one stage each.  The dominant term and the 6·N·D
 useful-FLOPs ratio are reported per cell.
 """
 from __future__ import annotations

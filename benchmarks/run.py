@@ -23,6 +23,9 @@ def main():
     ap.add_argument("--json", default=None)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     from . import bench_memory, bench_phase1, bench_scaling, bench_splits
 
     if args.quick:

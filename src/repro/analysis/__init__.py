@@ -12,9 +12,8 @@ Two passes, both runnable as modules and wired into CI as a hard gate:
                                  collective census against the engine's
                                  schedule budget, zero host callbacks in
                                  the fused body, donation on the one-shot
-                                 path, and a static Pallas VMEM cost model
-                                 cross-checked against the runtime
-                                 ``fits_resident_vmem`` gate.
+                                 path, and the static Phase 3 table and
+                                 byte model behind the program cache.
                                  ``python -m repro.analysis.audit --json``
 
 The paper's BSP model only pays off if every superstep stays on-device
@@ -24,14 +23,13 @@ verify those invariants statically, before a program ever runs.
 __all__ = [
     "Finding", "check_paths", "check_source",
     "ProgramAudit", "audit_graph", "census",
-    "expected_pallas_calls", "pallas_cost_model",
+    "phase3_cost_model",
 ]
 
 _HOMES = {
     "Finding": "lint", "check_paths": "lint", "check_source": "lint",
     "ProgramAudit": "jaxpr_audit", "audit_graph": "jaxpr_audit",
-    "census": "jaxpr_audit", "expected_pallas_calls": "jaxpr_audit",
-    "pallas_cost_model": "jaxpr_audit",
+    "census": "jaxpr_audit", "phase3_cost_model": "jaxpr_audit",
 }
 
 

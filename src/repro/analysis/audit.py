@@ -85,11 +85,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         tag = f"e_cap={prog['e_cap']} B={prog['batch'] or 1}"
         state = "ok" if prog["ok"] else "FAIL"
         a2a = prog["census"].get("all_to_all", 0)
-        plc = prog["census"].get("pallas_call", 0)
         print(f"  [{state}] {tag}: {a2a} all_to_all / "
               f"{prog['census'].get('all_gather', 0)} all_gather / "
-              f"{prog['census'].get('ppermute', 0)} ppermute / "
-              f"{plc} pallas_call "
+              f"{prog['census'].get('ppermute', 0)} ppermute "
               f"(scan length {prog['n_levels']})")
         for viol in prog["violations"]:
             print(f"         - {viol}")

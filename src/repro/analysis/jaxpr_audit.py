@@ -5,8 +5,8 @@ The engine publishes its collective schedule statically
 ``all_to_all`` per shipped field per table group; after the scan, either
 exactly one ``all_gather`` for the replicated device Phase 3, or — under
 ``sharded_phase3`` (DESIGN.md §11) — the ring schedule of
-:func:`repro.core.phase3.sharded_phase3_schedule` (``2R+7`` ``ppermute``
-eqns, 2 ``psum``, and at most one emission ``all_gather``, elided when
+:func:`repro.core.phase3.sharded_phase3_schedule` (9 ``ppermute``
+eqns, two of them in R-round doubling loops, 2 ``psum``, and at most one emission ``all_gather``, elided when
 ``gather_circuit=False``); nothing else.  This module traces each
 ``(bucket, batch-width)`` program the solver would cache, walks the
 closed jaxpr, and fails if the compiled program communicates — or syncs
@@ -18,13 +18,6 @@ with the host — anywhere the schedule says it must not:
   * zero host callbacks / infeed / outfeed in the fused body (a stray
     ``debug_print`` or ``pure_callback`` re-introduces per-level host
     syncs and silently serializes the BSP pipeline);
-  * Pallas ``pallas_call`` count equals the count implied by the Phase 3
-    round formulas plus the ``fits_resident_vmem`` gate — i.e. the
-    runtime kernel/jnp fallback decision is re-derived statically and
-    must agree with what was actually traced;
-  * the static VMEM cost model (resident jump tables + streamed blocks,
-    from the kernels' block specs) agrees with the runtime
-    ``fits_resident_vmem`` gate and stays under ``VMEM_CORE_BYTES``;
   * the one-shot program donates its state buffers
     (``jax.buffer_donor`` present in the lowering) and the cached /
     batched programs do NOT (their uploaded state must survive reuse).
@@ -41,7 +34,6 @@ Entry points: :func:`audit_program` (one traced program),
 from __future__ import annotations
 
 import dataclasses
-import inspect
 import math
 from collections import Counter
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -64,7 +56,7 @@ DONOR_MARK = "jax.buffer_donor"
 
 def _sub_jaxprs(eqn) -> List[Any]:
     """Nested jaxprs of one eqn (scan/while/cond bodies, pjit calls...)."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     out: List[Any] = []
     for v in eqn.params.values():
@@ -125,88 +117,46 @@ def _collective_bytes(jaxpr) -> Dict[str, int]:
 # ----------------------------------------------------------------------
 # static Phase 3 cost model (mirrors repro.core.phase3 without running it)
 # ----------------------------------------------------------------------
-def _phase3_block_default() -> int:
-    """Phase 3's kernel block size, read off its signature so the model
-    can't drift from the code."""
-    from ..core.phase3 import phase3_device
-
-    return int(inspect.signature(phase3_device).parameters["block"].default)
-
-
-def _sharded_block_default() -> int:
-    """Sharded Phase 3's kernel block size, read off its signature."""
-    from ..core.phase3 import phase3_sharded
-
-    return int(inspect.signature(phase3_sharded).parameters["block"].default)
-
-
 def _doubling_rounds(n: int) -> int:
-    """Pointer-doubling rounds both kernels run on an n-entry table."""
+    """Pointer-doubling rounds Phase 3 runs over an n-entry stub space."""
     return int(math.ceil(math.log2(max(2, n)))) + 1
 
 
-def pallas_cost_model(e_cap: int, batch: Optional[int],
+def phase3_cost_model(e_cap: int, batch: Optional[int],
                       n_parts: Optional[int] = None,
                       sharded: bool = False,
                       p3v_cap: int = 0) -> Dict[str, Any]:
-    """Static Pallas cost of one fused run: which doubling loops take the
-    kernel path, their VMEM footprint, and the resulting ``pallas_call``
-    eqn count.  Mirrors the gates in ``repro.core.phase3``: the CC loop
-    keeps 2 resident tables, list-rank keeps 3, both gated by
-    ``resolve_interpret(None) or fits_resident_vmem(...)``.
+    """Static Phase 3 table arithmetic of one fused run: the per-device
+    jump-table width, each doubling loop's round count, table bytes and
+    gathered elements, and the persistent working set.  The CC loop
+    gathers 2 tables per round, list-rank 3.
 
     With ``sharded=True`` (needs ``n_parts``) the model follows the
     sharded Phase 3 (DESIGN.md §11): tables are the per-device shard
-    (width ``S = shard_width(e_cap, n_parts)``, never padded — the shard
-    kernels shrink the block to divide S), the round count covers the
-    full ``n_parts*S`` stub space, and ``phase3_state_bytes`` is the
+    (width ``S = shard_width(e_cap, n_parts)``), the round count covers
+    the full ``n_parts*S`` stub space, and ``phase3_state_bytes`` is the
     per-device persistent working set — the O(2E/n) quantity the memory
     regression test pins (vs the replicated model's O(2E))."""
-    from ..kernels.pointer_double import (VMEM_CORE_BYTES,
-                                          VMEM_TABLE_BYTES, _pick_block,
-                                          fits_resident_vmem,
-                                          resident_table_bytes,
-                                          resolve_interpret)
-
     b = int(batch or 1)
     n_stubs = 2 * e_cap
-    interp = resolve_interpret(None)
     if sharded:
         if not n_parts:
             raise ValueError("sharded cost model needs n_parts")
         from ..core.phase3 import shard_width
 
         width = shard_width(e_cap, n_parts)
-        block = _sharded_block_default()
-        blk = _pick_block(width, block)
-        n_pad = width                    # shard tables are exactly S wide
         rounds = _doubling_rounds(n_parts * width)
     else:
-        block = _phase3_block_default()
-        n_pad = n_stubs + (-n_stubs) % block
-        width = n_pad
-        blk = min(block, n_pad)
+        width = n_stubs
         rounds = _doubling_rounds(n_stubs)
 
     loops = {}
     for name, n_tables in (("cc", 2), ("rank", 3)):
-        resident = resident_table_bytes(width, n_tables, batch=b)
-        fits = fits_resident_vmem(width, n_tables, batch=b)
-        # independent re-derivation of the gate from the block specs —
-        # must agree with the runtime helper (asserted by the audit)
-        model_fits = resident <= VMEM_TABLE_BYTES
-        # peak on-chip: resident tables + double-buffered query/output
-        # block tiles (n_tables in + n_tables out, itemsize 4)
-        peak = resident + 2 * (2 * n_tables) * blk * 4
         loops[name] = {
             "n_tables": n_tables,
             "rounds": rounds,
-            "resident_bytes": int(resident),
-            "peak_vmem_bytes": int(peak),
-            "fits_resident_vmem": bool(fits),
-            "model_fits": bool(model_fits),
-            "uses_kernel": bool(interp or fits),
-            "gather_flops": int(rounds * width * n_tables * b),
+            "table_bytes": int(width * n_tables * 4 * b),
+            "gather_elems": int(rounds * width * n_tables * b),
         }
     # per-device persistent Phase 3 working set, int32 throughout: the
     # six live arrays of CC + rank (mate, nxt/ptr, lab/dist, reach and
@@ -217,26 +167,12 @@ def pallas_cost_model(e_cap: int, batch: Optional[int],
         state_bytes += 4 * (int(p3v_cap) + 1) * 4 * b
     return {
         "n_stubs": n_stubs,
-        "padded": n_pad,
-        "block": block,
         "sharded": bool(sharded),
         "n_parts": int(n_parts) if n_parts else None,
         "phase3_table_width": int(width),
         "phase3_state_bytes": int(state_bytes),
-        "interpret": bool(interp),
-        "vmem_table_budget": int(VMEM_TABLE_BYTES),
-        "vmem_core_budget": int(VMEM_CORE_BYTES),
         "loops": loops,
-        "expected_pallas_calls": sum(
-            lp["rounds"] for lp in loops.values() if lp["uses_kernel"]),
     }
-
-
-def expected_pallas_calls(e_cap: int, batch: Optional[int] = None,
-                          n_parts: Optional[int] = None,
-                          sharded: bool = False) -> int:
-    return pallas_cost_model(e_cap, batch, n_parts=n_parts,
-                             sharded=sharded)["expected_pallas_calls"]
 
 
 # ----------------------------------------------------------------------
@@ -282,7 +218,7 @@ def program_cost_bytes(key, batch: Optional[int] = None,
     """
     e_cap, n_parts, _n_levels, caps = key[0], key[1], key[2], key[3]
     b = int(batch or 1)
-    cost = pallas_cost_model(
+    cost = phase3_cost_model(
         int(e_cap), b, n_parts=int(n_parts), sharded=bool(sharded),
         p3v_cap=(getattr(caps, "p3v_cap", 0) or int(e_cap)))
     per_device = engine_state_bytes(caps) * b + cost["phase3_state_bytes"]
@@ -364,7 +300,7 @@ def audit_program(eng, pg, e_cap: int, batch: Optional[int] = None,
 
     cen = census(closed)
     scans = _scan_bodies(closed)
-    cost = pallas_cost_model(e_cap, batch, n_parts=eng.n, sharded=sharded,
+    cost = phase3_cost_model(e_cap, batch, n_parts=eng.n, sharded=sharded,
                              p3v_cap=(eng.caps.p3v_cap or e_cap))
     v: List[str] = []
 
@@ -401,21 +337,6 @@ def audit_program(eng, pg, e_cap: int, batch: Optional[int] = None,
                        or "callback" in p)
     if host_hits:
         v.append(f"host-sync primitives in fused body: {host_hits}")
-
-    got_pallas = cen.get("pallas_call", 0)
-    if got_pallas != cost["expected_pallas_calls"]:
-        v.append(f"pallas_call: traced {got_pallas}, cost model expects "
-                 f"{cost['expected_pallas_calls']} "
-                 f"(rounds x kernel-gated loops)")
-    for name, lp in cost["loops"].items():
-        if lp["fits_resident_vmem"] != lp["model_fits"]:
-            v.append(f"{name}: block-spec cost model "
-                     f"({lp['resident_bytes']}B resident) disagrees with "
-                     f"fits_resident_vmem gate")
-        if lp["uses_kernel"] and not cost["interpret"] and \
-                lp["peak_vmem_bytes"] > cost["vmem_core_budget"]:
-            v.append(f"{name}: peak VMEM {lp['peak_vmem_bytes']}B exceeds "
-                     f"core budget {cost['vmem_core_budget']}B")
 
     # measured bytes moved (per shard, per scan iteration for scanned
     # collectives) + caps-derived closed form for the report

@@ -167,8 +167,8 @@ class _Taint:
 
     Parameters without a default are tracers; parameters *with* a default
     are treated as static configuration (the engine threads e.g.
-    ``interpret=None``/``block=1024`` through traced helpers, and
-    branching on those is legitimate trace-time specialization), as are
+    ``splice_rounds=64``/``gather_circuit=True`` through traced helpers,
+    and branching on those is legitimate trace-time specialization), as are
     parameters annotated with a static type (``cap: int``,
     ``cfg: LMConfig`` — jit static_argnames / closure-config idiom).
     Shape/dtype access, identity tests and static builtins launder taint

@@ -48,6 +48,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..parallel.compat import shard_map
+from . import bounded
 from .graph import PartitionedGraph
 from .phase1 import (
     BIG,
@@ -279,8 +280,8 @@ def fused_collective_budget(n_levels: int, num_edges: Optional[int] = None,
     (default) performs ONE ``all_gather`` and nothing else; the *sharded*
     Phase 3 (``sharded_phase3=True``, needs ``num_edges``/``n_parts``)
     instead runs the ring schedule of
-    :func:`repro.core.phase3.sharded_phase3_schedule` — ``2R+7``
-    ``ppermute`` ring loops and 2 ``psum`` eqns, with the single
+    :func:`repro.core.phase3.sharded_phase3_schedule` — 9
+    ``ppermute`` ring loops (2R+7 rings at run time) and 2 ``psum`` eqns, with the single
     ``all_gather`` deferred to circuit emission (and elided entirely
     under ``gather_circuit=False``).  Nothing else may communicate —
     ``repro.analysis.jaxpr_audit`` walks the compiled jaxpr and fails the
@@ -332,13 +333,11 @@ def _route(dest: jnp.ndarray, mask: jnp.ndarray, fields, n: int, lane: int):
     """Scatter entries into an [n, lane] send buffer keyed by dest device.
     Returns (buffers..., buf_mask, overflow)."""
     key = jnp.where(mask, dest, n)  # pads route to virtual slot n
-    order = jnp.argsort(key, stable=True)
+    order = bounded.argsort(key)
     kd = key[order]
     idx = jnp.arange(kd.shape[0], dtype=I32)
     newseg = jnp.concatenate([jnp.ones((1,), bool), kd[1:] != kd[:-1]])
-    seg_start = jax.lax.associative_scan(
-        jnp.maximum, jnp.where(newseg, idx, 0)
-    )
+    seg_start = bounded.cummax(jnp.where(newseg, idx, 0))
     lane_pos = idx - seg_start
     ok = (kd < n) & (lane_pos < lane)
     overflow = jnp.any((kd < n) & (lane_pos >= lane))
@@ -354,7 +353,7 @@ def _route(dest: jnp.ndarray, mask: jnp.ndarray, fields, n: int, lane: int):
 
 def _compact_rows(fields, mask, cap: int):
     """Compact a flat masked table to ``cap`` rows (valid-first)."""
-    order = jnp.argsort(~mask, stable=True)
+    order = bounded.argsort(~mask)
     overflow = jnp.sum(mask) > cap
     outs = [f[order][:cap] for f in fields]
     return outs, mask[order][:cap], overflow
@@ -854,7 +853,7 @@ class DistributedEngine:
             are device-disjoint, so the scatter is conflict-free;
           · Phase 3 on-device: all_gather the mate shards, then the pivot
             splice + list-rank emission (``phase3_device``), replicated
-            per device, Pallas ``pointer_double`` as the doubling backend.
+            per device, XLA gather rounds as the doubling backend.
 
         The program's outputs (circuit, mate, flags, metrics) are fetched
         with ONE host transfer in :meth:`run`.
@@ -921,17 +920,13 @@ class DistributedEngine:
                 mate = jax.lax.all_gather(mate_sh[:S], axes,
                                           tiled=True)[:n_stubs]
                 circuit, mate2, ok3 = phase3_device(
-                    mate, sv, splice_rounds=c.phase3_rounds,
-                    batch=(batch or 1),
-                )
+                    mate, sv, splice_rounds=c.phase3_rounds)
                 return circuit, mate2, flags, metrics, ok3
             # DESIGN.md §11: Phase 3 runs on the accumulator shards
             # directly — no mate all_gather; sv arrives sharded too.
             res3 = phase3_sharded(
                 mate_sh[:S], sv, axes, n, n_stubs, p3v,
-                splice_rounds=c.phase3_rounds, gather_circuit=gather,
-                batch=(batch or 1),
-            )
+                splice_rounds=c.phase3_rounds, gather_circuit=gather)
             if gather:
                 circuit, mate2, ok3 = res3
                 return circuit, mate2, flags, metrics, ok3
@@ -1313,10 +1308,10 @@ def _fit(x: jnp.ndarray, cap: int, fill=None):
 
 
 def _fit_masked(x: jnp.ndarray, mask: jnp.ndarray, cap: int):
-    order = jnp.argsort(~mask, stable=True)
+    order = bounded.argsort(~mask)
     return _fit(x[order], cap)
 
 
 def _fit_mask(mask: jnp.ndarray, cap: int):
-    order = jnp.argsort(~mask, stable=True)
+    order = bounded.argsort(~mask)
     return _fit(mask[order], cap, fill=False)

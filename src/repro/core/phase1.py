@@ -31,6 +31,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from . import bounded
+
 I32 = jnp.int32
 BIG = jnp.iinfo(jnp.int32).max
 
@@ -41,8 +43,9 @@ class Phase1Caps:
     touch_cap: int          # max representative pairs at boundary vertices
     hook_rounds: int = 0    # 0 → ceil(log2(comp universe)) + 2
     splice_rounds: int = 12
-    static_splice: bool = False  # unroll splice rounds (roofline analysis:
-                                 # while-loop bodies are cost-counted once)
+    static_splice: bool = False  # unroll splice and hook rounds (roofline
+                                 # analysis: while-loop bodies are
+                                 # cost-counted once)
 
 
 class OpenTable(NamedTuple):
@@ -101,7 +104,7 @@ def empty_touch(cap: int) -> TouchTable:
 
 def _compact(arrays, mask, cap: int):
     """Move valid entries to the front and truncate to ``cap``."""
-    order = jnp.argsort(~mask, stable=True)
+    order = bounded.argsort(~mask)
     overflow = jnp.sum(mask) > cap
     outs = tuple(a[order][:cap] for a in arrays)
     return outs, mask[order][:cap], overflow
@@ -114,18 +117,26 @@ def _seg_starts(sorted_keys, idx_dtype=I32):
     newseg = jnp.concatenate(
         [jnp.ones((1,), bool), sorted_keys[1:] != sorted_keys[:-1]]
     )
-    return jax.lax.associative_scan(jnp.maximum, jnp.where(newseg, idx, 0))
+    return bounded.cummax(jnp.where(newseg, idx, 0))
 
 
-def _cc_hook_jump(ca, cb, emask, universe, rounds: int):
+def _cc_hook_jump(ca, cb, emask, universe, rounds: int,
+                  static: bool = False):
     """Min-label connected components over a value-keyed graph.
 
     Nodes are the values in ``universe`` ([K], BIG-padded); edges are
     (ca[i], cb[i]) where ``emask[i]``.  Returns (sorted universe,
     root *value* per universe slot, converged flag).
+
+    Runs at most ``rounds`` hook/jump/contract rounds and stops at the
+    first round that leaves ``(lab, ea, eb)`` unchanged: a round is a
+    function of that state alone, so every later round would repeat it
+    and the result equals the full ``rounds``.  ``static`` unrolls all
+    ``rounds`` instead (roofline analysis: a ``while_loop`` body is
+    cost-counted once).
     """
     K = universe.shape[0]
-    uniq = jnp.sort(universe)
+    uniq = bounded.sort(universe)
     ia = jnp.clip(jnp.searchsorted(uniq, jnp.where(emask, ca, BIG)), 0, K - 1).astype(I32)
     ib = jnp.clip(jnp.searchsorted(uniq, jnp.where(emask, cb, BIG)), 0, K - 1).astype(I32)
     ia = jnp.where(emask, ia, K - 1)
@@ -138,18 +149,30 @@ def _cc_hook_jump(ca, cb, emask, universe, rounds: int):
         tgt = jnp.concatenate([ea, eb])
         return jnp.minimum(lab, jax.ops.segment_min(both, tgt, num_segments=K))
 
-    # Unrolled python loop (rounds is static): keeps every round visible to
-    # cost_analysis — while/fori bodies are otherwise counted once, which
-    # would hide O(log K) of the superstep's work from the roofline.
-    ea, eb = ia, ib
-    for _ in range(rounds):
+    def round_(lab, ea, eb):
         lab = hook(lab, ea, eb)
         lab = lab[lab]
         lab = lab[lab]
         # Borůvka-style edge contraction: relabel endpoints to super-nodes
         # so the next hook propagates between contracted components —
         # this is what makes convergence O(log K) instead of O(diameter).
-        ea, eb = lab[ea], lab[eb]
+        return lab, lab[ea], lab[eb]
+
+    ea, eb = ia, ib
+    if static:
+        for _ in range(rounds):
+            lab, ea, eb = round_(lab, ea, eb)
+    else:
+        def body(state):
+            lab, ea, eb, _, r = state
+            nlab, nea, neb = round_(lab, ea, eb)
+            changed = (jnp.any(nlab != lab) | jnp.any(nea != ea)
+                       | jnp.any(neb != eb))
+            return nlab, nea, neb, changed, r + 1
+
+        lab, ea, eb, _, _ = jax.lax.while_loop(
+            lambda st: st[3] & (st[4] < rounds), body,
+            (lab, ea, eb, jnp.array(True), jnp.array(0, I32)))
     converged = jnp.all(hook(lab, ea, eb) == lab)
     return uniq, uniq[lab], converged
 
@@ -197,7 +220,7 @@ def phase1_local(
     vkey = jnp.where(pool_mask, pool_vert, BIG)
     # §Perf (euler H-E1'): drop the stub tiebreak key — stable argsort is
     # already deterministic — one sort pass instead of lexsort's two
-    order = jnp.argsort(vkey, stable=True)
+    order = bounded.argsort(vkey)
     sv, ss = vkey[order], pool_stub[order]
     sc, sl, sm = pool_comp[order], pool_la[order], pool_mask[order]
     pos = jnp.arange(P, dtype=I32) - _seg_starts(sv)
@@ -222,6 +245,7 @@ def phase1_local(
     uniq, root_val, cc_ok = _cc_hook_jump(
         pr_ca, pr_cb, pr_mask, universe,
         caps.hook_rounds or int(math.ceil(math.log2(max(2, universe.shape[0])))) + 2,
+        static=caps.static_splice,
     )
     open_comp = _value_lookup(uniq, root_val, jnp.where(left_mask, sc, BIG))
     pair_comp = _value_lookup(uniq, root_val, pr_ca)
@@ -247,7 +271,7 @@ def phase1_local(
     PC = q_s1.shape[0]
     q_c_pre = q_c          # pre-splice comps of the compacted pair table
 
-    oc = jnp.sort(open_comp)  # sorted open comps (BIG-padded) for path tests
+    oc = bounded.sort(open_comp)  # sorted open comps (BIG-padded)
 
     def is_path(comps, oc_sorted):
         j = jnp.clip(jnp.searchsorted(oc_sorted, comps), 0,
@@ -260,7 +284,7 @@ def phase1_local(
     def splice_round(state):
         s2, cmp_, oc_sorted, _, rounds_left = state
         vm = jnp.where(q_m, q_v, BIG)
-        order2 = jnp.lexsort((cmp_, vm))   # H-E1': s1 tiebreak dropped
+        order2 = bounded.lexsort((cmp_, vm))  # H-E1': no s1 tiebreak
         gv, gc = vm[order2], cmp_[order2]
         gs2 = s2[order2]
         gm = q_m[order2]
@@ -289,7 +313,7 @@ def phase1_local(
         act = take & (n_take[seg] >= 2)
         # rotation among act members, circular within vertex segment
         akey = jnp.where(act, gv, BIG)
-        o4 = jnp.argsort(akey, stable=True)
+        o4 = bounded.argsort(akey)
         hv, hs2, hc = akey[o4], gs2[o4], gc[o4]
         hm = act[o4]
         hstart = _seg_starts(hv)
@@ -308,7 +332,7 @@ def phase1_local(
         # comp relabel map (from → min comp at its rotation vertex)
         mfrom = jnp.where(hm, hc, BIG)
         mto = jnp.where(hm, rot_c, BIG)
-        mo = jnp.argsort(mfrom, stable=True)
+        mo = bounded.argsort(mfrom)
         mfrom, mto = mfrom[mo], mto[mo]
 
         def relabel(vals):
@@ -316,7 +340,7 @@ def phase1_local(
             return jnp.where(mfrom[j] == vals, mto[j], vals)
 
         cmp_new = relabel(cmp_)
-        oc_new = jnp.sort(relabel(oc_sorted))
+        oc_new = bounded.sort(relabel(oc_sorted))
         return s2_new, cmp_new, oc_new, changed, rounds_left - 1
 
     def cond(state):
@@ -350,6 +374,7 @@ def phase1_local(
         jnp.concatenate([universe, jnp.where(q_m, q_c, BIG)]),
         caps.hook_rounds or int(
             math.ceil(math.log2(max(2, 2 * universe.shape[0])))) + 2,
+        static=caps.static_splice,
     )
     open_comp_final = _value_lookup(uniq3, root3, open_comp)
 
@@ -364,7 +389,7 @@ def phase1_local(
     keep = q_m & (q_la > level)
     tv = jnp.where(keep, q_v, BIG)
     tc = jnp.where(keep, q_c, BIG)
-    ot = jnp.lexsort((tc, tv))             # H-E1': s1 tiebreak dropped
+    ot = bounded.lexsort((tc, tv))        # H-E1': s1 tiebreak dropped
     dv, dc = tv[ot], tc[ot]
     dup2 = jnp.concatenate(
         [jnp.zeros((1,), bool), (dv[1:] == dv[:-1]) & (dc[1:] == dc[:-1])]
@@ -375,7 +400,7 @@ def phase1_local(
     )
     new_touch = TouchTable(t_s1, t_s2, t_v, t_la, t_c, t_m)
 
-    live = jnp.sort(jnp.concatenate(
+    live = bounded.sort(jnp.concatenate(
         [jnp.where(o_mask, o_comp, BIG), jnp.where(t_m, t_c, BIG)]
     ))
     n_comp = jnp.sum(

@@ -11,15 +11,15 @@ they share semantics and are cross-checked in tests.  The device path
 (:func:`splice_components_jnp` + :func:`circuit_from_mate_jnp` behind
 :func:`phase3_device`) is fully jittable and runs inside the fused engine
 program (DESIGN.md §4): the scipy ``connected_components`` call becomes
-pointer-doubling min-label propagation over the cycle structure (the
-Pallas ``pointer_double`` kernel, compiled on TPU / interpret elsewhere)
+pointer-doubling min-label propagation over the cycle structure (the XLA
+gather rounds of ``repro.kernels.ref``, the same program on every backend)
 and the per-vertex rotation becomes the same sort + segment voting scheme
 Phase 1 uses for its splice rounds.
 """
 from __future__ import annotations
 
 import math
-from typing import Optional, Tuple
+from typing import Tuple
 
 import numpy as np
 
@@ -27,10 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import ref as _kref
-from ..kernels.pointer_double import (_pick_block, fits_resident_vmem,
-                                      pointer_double, pointer_double_rank,
-                                      pointer_double_rank_shard,
-                                      pointer_double_shard, resolve_interpret)
+from . import bounded
 from .phase1 import BIG, I32, _seg_starts
 
 
@@ -67,23 +64,15 @@ def circuit_from_mate_np(mate: np.ndarray, start_stub: int = -1) -> np.ndarray:
     return order.astype(np.int64)
 
 
-def circuit_from_mate_jnp(mate: jnp.ndarray, start_stub: jnp.ndarray,
-                          use_pallas: bool = False,
-                          interpret: Optional[bool] = None,
-                          block: int = 1024,
-                          batch: int = 1) -> jnp.ndarray:
+def circuit_from_mate_jnp(mate: jnp.ndarray,
+                          start_stub: jnp.ndarray) -> jnp.ndarray:
     """JAX list-ranking twin of :func:`circuit_from_mate_np`.
 
     Returns arrival stubs in walk order, padded with -1 where ``mate`` is
     invalid (padding slots).  Static shapes: output has ``len(mate)//2``
-    entries (E slots).
-
-    With ``use_pallas`` the doubling rounds run through the Pallas
-    ``pointer_double_rank`` kernel (compiled on TPU, interpret elsewhere);
-    both backends produce bit-identical output.  ``batch`` declares how
-    many instances an enclosing ``vmap`` runs (the engine's batched fused
-    program); it only scales the VMEM-residency gate — per-element
-    semantics are unchanged.
+    entries (E slots).  The doubling rounds are
+    :func:`repro.kernels.ref.pointer_double_rank_ref` under one
+    ``fori_loop``, so the program's size does not grow with the round count.
     """
     n_stubs = mate.shape[0]
     iota = jnp.arange(n_stubs, dtype=mate.dtype)
@@ -91,47 +80,13 @@ def circuit_from_mate_jnp(mate: jnp.ndarray, start_stub: jnp.ndarray,
     nxt = jnp.where(valid, mate ^ 1, iota)
 
     t = mate[start_stub ^ 1]
-    ptr = nxt.at[t].set(t)
+    ptr = nxt.at[t].set(t).astype(I32)
     dist = jnp.ones(n_stubs, dtype=jnp.int32).at[t].set(0)
-    reach = jnp.zeros(n_stubs, dtype=bool).at[t].set(True)
+    reach = jnp.zeros(n_stubs, dtype=I32).at[t].set(1)
     rounds = int(np.ceil(np.log2(max(2, n_stubs)))) + 1
-
-    # The compiled kernel keeps 3 tables VMEM-resident; beyond that budget
-    # fall back to the (bit-identical) jnp doubling, which XLA schedules
-    # against HBM.  Interpret mode has no residency constraint.
-    pad = (-n_stubs) % block
-    if use_pallas and not (resolve_interpret(interpret)
-                           or fits_resident_vmem(n_stubs + pad, 3,
-                                                 batch=batch)):
-        use_pallas = False
-    if use_pallas:
-        # Pad to a block multiple with self-looping halt slots (dist 0 so
-        # they never overflow; unreachable so they never enter the orbit).
-        ptr_p = ptr.astype(I32)
-        dist_p = dist
-        reach_p = reach.astype(I32)
-        if pad:
-            ip = jnp.arange(n_stubs, n_stubs + pad, dtype=I32)
-            ptr_p = jnp.concatenate([ptr_p, ip])
-            dist_p = jnp.concatenate([dist_p, jnp.zeros((pad,), jnp.int32)])
-            reach_p = jnp.concatenate([reach_p, jnp.zeros((pad,), I32)])
-        for _ in range(rounds):
-            ptr_p, dist_p, reach_p = pointer_double_rank(
-                ptr_p, dist_p, reach_p, block=block, interpret=interpret
-            )
-        dist = dist_p[:n_stubs]
-        reach = reach_p[:n_stubs] > 0
-    else:
-        def body(_, carry):
-            dist, reach, ptr = carry
-            dist = dist + dist[ptr]
-            reach = reach | reach[ptr]
-            ptr = ptr[ptr]
-            return dist, reach, ptr
-
-        dist, reach, ptr = jax.lax.fori_loop(0, rounds, body,
-                                             (dist, reach, ptr))
-
+    _, dist, reach = jax.lax.fori_loop(
+        0, rounds, lambda _, c: _kref.pointer_double_rank_ref(*c),
+        (ptr, dist, reach))
     return emit_circuit(valid, dist, reach)
 
 
@@ -147,7 +102,7 @@ def emit_circuit(valid: jnp.ndarray, dist: jnp.ndarray,
     """
     on_orbit = (reach > 0) & valid
     key = jnp.where(on_orbit, -dist, jnp.iinfo(jnp.int32).max)
-    order = jnp.argsort(key, stable=True)
+    order = bounded.argsort(key)
     E = valid.shape[0] // 2
     out = order[:E].astype(jnp.int32)
     member = on_orbit[out]
@@ -242,9 +197,7 @@ def splice_components_np(
 # device Phase 3 (jittable; runs inside the fused engine program)
 # ---------------------------------------------------------------------------
 
-def _cc_cycle_labels(mate: jnp.ndarray, valid: jnp.ndarray,
-                     interpret: Optional[bool] = None,
-                     block: int = 1024, batch: int = 1) -> jnp.ndarray:
+def _cc_cycle_labels(mate: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
     """Component labels (min member stub id) of the sibling∘mate cycle
     structure, by pointer-doubling min-label propagation.
 
@@ -257,25 +210,9 @@ def _cc_cycle_labels(mate: jnp.ndarray, valid: jnp.ndarray,
     n = mate.shape[0]
     iota = jnp.arange(n, dtype=I32)
     nxt = jnp.where(valid, mate ^ 1, iota).astype(I32)  # walk successor
-    lab = iota
-    pad = (-n) % block
-    if pad:
-        ip = jnp.arange(n, n + pad, dtype=I32)          # self-looping pads
-        nxt = jnp.concatenate([nxt, ip])
-        lab = jnp.concatenate([lab, ip])
     rounds = int(math.ceil(math.log2(max(2, n)))) + 1
-    # Compiled-kernel VMEM gate: the resident-table layout holds 2 [n]
-    # tables; whole-graph tables beyond the budget use the bit-identical
-    # jnp doubling round instead (interpret mode is unconstrained).
-    use_kernel = resolve_interpret(interpret) or fits_resident_vmem(
-        n + pad, 2, batch=batch)
-    for _ in range(rounds):
-        if use_kernel:
-            nxt, lab = pointer_double(nxt, lab, block=block,
-                                      interpret=interpret)
-        else:
-            nxt, lab = _kref.pointer_double_ref(nxt, lab)
-    lab = lab[:n]
+    _, lab = jax.lax.fori_loop(
+        0, rounds, lambda _, c: _kref.pointer_double_ref(*c), (nxt, iota))
     return jnp.minimum(lab, lab[iota ^ 1])
 
 
@@ -284,9 +221,6 @@ def splice_components_jnp(
     stub_vertex: jnp.ndarray,
     valid: jnp.ndarray,
     rounds: int = 64,
-    interpret: Optional[bool] = None,
-    block: int = 1024,
-    batch: int = 1,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Jittable twin of :func:`splice_components_np` for perfect matchings.
 
@@ -308,15 +242,14 @@ def splice_components_jnp(
     iota = jnp.arange(n, dtype=I32)
     mate = mate.astype(I32)
     sv = stub_vertex.astype(I32)
-    lab0 = _cc_cycle_labels(mate, valid, interpret=interpret, block=block,
-                            batch=batch)
+    lab0 = _cc_cycle_labels(mate, valid)
 
     def round_fn(state):
         mate, lab, _, r = state
         cm = valid & (mate > iota)                 # canonical stub per pair
         vkey = jnp.where(cm, sv, BIG)
         ckey = jnp.where(cm, lab, BIG)
-        order = jnp.lexsort((ckey, vkey))
+        order = bounded.lexsort((ckey, vkey))
         gv, gc = vkey[order], ckey[order]
         gs = jnp.where(cm, iota, BIG)[order]
         gm = cm[order]
@@ -338,7 +271,7 @@ def splice_components_jnp(
         act = voted & (n_take[seg] >= 2)
         # circular mate rotation within each pivot vertex's act group
         akey = jnp.where(act, gv, BIG)
-        o2 = jnp.argsort(akey, stable=True)
+        o2 = bounded.argsort(akey)
         hv, hs, hc = akey[o2], gs[o2], gc[o2]
         hm = act[o2]
         hstart = _seg_starts(hv)
@@ -373,9 +306,7 @@ def splice_components_jnp(
 
 
 def phase3_device(mate: jnp.ndarray, stub_vertex: jnp.ndarray,
-                  splice_rounds: int = 64,
-                  interpret: Optional[bool] = None,
-                  block: int = 1024, batch: int = 1):
+                  splice_rounds: int = 64):
     """Full on-device Phase 3: pivot splice + list-rank emission.
 
     Shared by the fused engine program (where it runs replicated inside the
@@ -384,22 +315,15 @@ def phase3_device(mate: jnp.ndarray, stub_vertex: jnp.ndarray,
     circuits whenever their mate arrays agree.
 
     The batched fused program wraps this whole function in ``jax.vmap``
-    (one call per graph in the batch); ``batch`` is that vmap's static
-    width, threaded down so the Pallas kernels' VMEM-residency gates can
-    account for batched grids (DESIGN.md §8).  It never changes
-    per-element results.
+    (one call per graph in the batch, DESIGN.md §8).
 
     Returns ``(circuit [E], mate', splice_converged)``.
     """
     valid = mate >= 0
     mate2, ok = splice_components_jnp(mate, stub_vertex, valid,
-                                      rounds=splice_rounds,
-                                      interpret=interpret, block=block,
-                                      batch=batch)
+                                      rounds=splice_rounds)
     start = jnp.argmax(valid).astype(I32)
-    circuit = circuit_from_mate_jnp(mate2, start, use_pallas=True,
-                                    interpret=interpret, block=block,
-                                    batch=batch)
+    circuit = circuit_from_mate_jnp(mate2, start)
     return circuit, mate2, ok
 
 
@@ -448,17 +372,18 @@ def sharded_phase3_schedule(num_edges: int, n_parts: int,
                             gather_circuit: bool = True) -> dict:
     """The sharded Phase 3's static collective schedule, counted in jaxpr
     *eqns* (ring loops trace one ppermute eqn each; the runtime executes
-    each ``n_parts`` times per loop).  Shared by the engine's published
-    budget (``fused_collective_budget``) and the analysis cost model so
-    the two can never drift.
+    each ``n_parts`` times per loop, and each doubling ring once per
+    round: 2R+7 rings in all).  Shared by the engine's published budget
+    (``fused_collective_budget``) and the analysis cost model so the two
+    can never drift.
 
-      · CC doubling: one table-rotation ring per round;
+      · CC doubling: one table-rotation ring inside the R-round loop;
       · pivot splice (inside the while body, traced once): 6 rings —
         record ship, vote scatter, vote readback, mate write, relabel
         scatter, relabel readback — plus 1 ``psum`` for the global
         `changed` flag;
       · rank: 1 ring-min for the start stub, 1 ``psum`` fetching the halt
-        stub's mate, one rotation ring per round;
+        stub's mate, one rotation ring inside the R-round loop;
       · emission: 1 ``all_gather`` (elided when ``gather_circuit=False``,
         where the rank shards leave the program still sharded).
     """
@@ -470,7 +395,7 @@ def sharded_phase3_schedule(num_edges: int, n_parts: int,
         "stub_space": total,
         "doubling_rounds": rounds,
         "splice_rings": 6,
-        "ppermute": 2 * rounds + 6 + 1,
+        "ppermute": 2 + 6 + 1,
         "psum": 2,
         "all_gather": 1 if gather_circuit else 0,
     }
@@ -480,9 +405,7 @@ def _ring_perm(n: int):
     return [(i, (i + 1) % n) for i in range(n)]
 
 
-def _cc_labels_sharded(mate_sh: jnp.ndarray, axes, n: int,
-                       interpret: Optional[bool] = None,
-                       block: int = 1024, batch: int = 1) -> jnp.ndarray:
+def _cc_labels_sharded(mate_sh: jnp.ndarray, axes, n: int) -> jnp.ndarray:
     """Sharded twin of :func:`_cc_cycle_labels`: min-label propagation by
     pointer doubling where each round resolves remote pointers with one
     full ring rotation of the (nxt, lab) table shards."""
@@ -491,33 +414,26 @@ def _cc_labels_sharded(mate_sh: jnp.ndarray, axes, n: int,
     gid = me * S + jnp.arange(S, dtype=I32)
     valid = mate_sh >= 0
     nxt = jnp.where(valid, mate_sh ^ 1, gid).astype(I32)
-    lab = gid
     perm = _ring_perm(n)
     rounds = int(math.ceil(math.log2(max(2, n * S)))) + 1
-    blk = _pick_block(S, block)
-    use_kernel = resolve_interpret(interpret) or fits_resident_vmem(
-        S, 2, batch=batch)
-    for _ in range(rounds):
-        q = nxt
 
-        def step(k, carry):
-            tbl, a_nxt, a_lab = carry
+    def round_(_, carry):
+        nxt, lab = carry
+
+        def step(k, c):
+            tbl, a_nxt, a_lab = c
             base = ((jnp.mod(me - k, n)) * S).astype(I32)[None]
-            if use_kernel:
-                a_nxt, a_lab = pointer_double_shard(
-                    q, a_nxt, a_lab, base, tbl[0], tbl[1],
-                    s_real=S, block=blk, interpret=interpret)
-            else:
-                a_nxt, a_lab = _kref.pointer_double_shard_ref(
-                    q, a_nxt, a_lab, base, tbl[0], tbl[1], s_real=S)
+            a_nxt, a_lab = _kref.pointer_double_shard_ref(
+                nxt, a_nxt, a_lab, base, tbl[0], tbl[1], s_real=S)
             tbl = jax.lax.ppermute(tbl, axes, perm)
             return tbl, a_nxt, a_lab
 
         _, a_nxt, a_lab = jax.lax.fori_loop(
             0, n, step,
-            (jnp.stack([nxt, lab]), q, jnp.full((S,), BIG, I32)))
-        nxt = a_nxt
-        lab = jnp.minimum(lab, a_lab)
+            (jnp.stack([nxt, lab]), nxt, jnp.full((S,), BIG, I32)))
+        return a_nxt, jnp.minimum(lab, a_lab)
+
+    _, lab = jax.lax.fori_loop(0, rounds, round_, (nxt, gid))
     iota = jnp.arange(S, dtype=I32)
     return jnp.minimum(lab, lab[iota ^ 1])
 
@@ -529,9 +445,6 @@ def splice_components_sharded(
     n: int,
     p3v_cap: int,
     rounds: int = 64,
-    interpret: Optional[bool] = None,
-    block: int = 1024,
-    batch: int = 1,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Sharded twin of :func:`splice_components_jnp`.
 
@@ -551,8 +464,7 @@ def splice_components_sharded(
     mate_sh = mate_sh.astype(I32)
     sv_sh = sv_sh.astype(I32)
     perm = _ring_perm(n)
-    lab0 = _cc_labels_sharded(mate_sh, axes, n, interpret=interpret,
-                              block=block, batch=batch)
+    lab0 = _cc_labels_sharded(mate_sh, axes, n)
     lo, hi = me * S, me * S + S
 
     def round_fn(state):
@@ -565,7 +477,7 @@ def splice_components_sharded(
             buf, tbl, cnt, of_t = carry
             bs, bv, bc, bm, bmk = buf
             take = (bmk > 0) & (jnp.mod(bv, n) == me)
-            pos = cnt + jnp.cumsum(take.astype(I32)) - 1
+            pos = cnt + bounded.cumsum(take.astype(I32)) - 1
             okw = take & (pos < p3v_cap)
             slot = jnp.where(okw, pos, p3v_cap)
             vals = jnp.stack([bv, bc, bs, bm])
@@ -585,7 +497,7 @@ def splice_components_sharded(
         tv, tc, ts, tm = (tbl[i, :p3v_cap] for i in range(4))
 
         # ---- local per-vertex logic (the oracle's, verbatim) ----
-        order = jnp.lexsort((ts, tc, tv))
+        order = bounded.lexsort((ts, tc, tv))
         gv, gc, gs, gm = tv[order], tc[order], ts[order], tm[order]
         gmk = gv < BIG
         dup = jnp.concatenate(
@@ -633,7 +545,7 @@ def splice_components_sharded(
 
         # circular rotation pairs within each pivot vertex's act group
         akey = jnp.where(act, gv, BIG)
-        o2 = jnp.argsort(akey, stable=True)
+        o2 = bounded.argsort(akey)
         hv, hs, hc = akey[o2], gs[o2], gc[o2]
         hmate = gm[o2]
         hm = act[o2]
@@ -708,10 +620,8 @@ def splice_components_sharded(
     return mate_sh, ~still_changing & ~of
 
 
-def _rank_sharded(mate_sh: jnp.ndarray, axes, n: int,
-                  interpret: Optional[bool] = None,
-                  block: int = 1024, batch: int = 1
-                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def _rank_sharded(mate_sh: jnp.ndarray, axes,
+                  n: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Sharded list ranking: the doubling loop of
     :func:`circuit_from_mate_jnp` over rotating (ptr, dist, reach) table
     shards.  Returns the local (dist, reach) slices."""
@@ -740,42 +650,32 @@ def _rank_sharded(mate_sh: jnp.ndarray, axes, n: int,
     dist = jnp.where(gid == t, 0, 1).astype(jnp.int32)
     reach = (gid == t).astype(I32)
     rounds = int(math.ceil(math.log2(max(2, n * S)))) + 1
-    blk = _pick_block(S, block)
-    use_kernel = resolve_interpret(interpret) or fits_resident_vmem(
-        S, 3, batch=batch)
-    for _ in range(rounds):
-        qq = ptr
+    zero = jnp.zeros((S,), I32)
 
-        def step(k, carry):
-            tbl, a_ptr, a_dist, a_reach = carry
+    def round_(_, carry):
+        ptr, dist, reach = carry
+
+        def step(k, c):
+            tbl, a_ptr, a_dist, a_reach = c
             base = ((jnp.mod(me - k, n)) * S).astype(I32)[None]
-            if use_kernel:
-                a_ptr, a_dist, a_reach = pointer_double_rank_shard(
-                    qq, a_ptr, a_dist, a_reach, base,
-                    tbl[0], tbl[1], tbl[2],
-                    s_real=S, block=blk, interpret=interpret)
-            else:
-                a_ptr, a_dist, a_reach = _kref.pointer_double_rank_shard_ref(
-                    qq, a_ptr, a_dist, a_reach, base,
-                    tbl[0], tbl[1], tbl[2], s_real=S)
+            a_ptr, a_dist, a_reach = _kref.pointer_double_rank_shard_ref(
+                ptr, a_ptr, a_dist, a_reach, base,
+                tbl[0], tbl[1], tbl[2], s_real=S)
             tbl = jax.lax.ppermute(tbl, axes, perm)
             return tbl, a_ptr, a_dist, a_reach
 
-        zero = jnp.zeros((S,), I32)
         _, a_ptr, a_dist, a_reach = jax.lax.fori_loop(
-            0, n, step, (jnp.stack([ptr, dist, reach]), qq, zero, zero))
-        ptr = a_ptr
-        dist = dist + a_dist
-        reach = jnp.maximum(reach, a_reach)
+            0, n, step, (jnp.stack([ptr, dist, reach]), ptr, zero, zero))
+        return a_ptr, dist + a_dist, jnp.maximum(reach, a_reach)
+
+    _, dist, reach = jax.lax.fori_loop(0, rounds, round_, (ptr, dist, reach))
     return dist, reach
 
 
 def phase3_sharded(mate_sh: jnp.ndarray, sv_sh: jnp.ndarray, axes, n: int,
                    n_stubs: int, p3v_cap: int,
                    splice_rounds: int = 64,
-                   gather_circuit: bool = True,
-                   interpret: Optional[bool] = None,
-                   block: int = 1024, batch: int = 1):
+                   gather_circuit: bool = True):
     """Full sharded Phase 3 for one device's [S] stub shard.
 
     With ``gather_circuit=True`` (the default) the run's ONE
@@ -788,11 +688,8 @@ def phase3_sharded(mate_sh: jnp.ndarray, sv_sh: jnp.ndarray, axes, n: int,
     from the fetched shards via the same :func:`emit_circuit` ordering.
     """
     mate2_sh, ok = splice_components_sharded(
-        mate_sh, sv_sh, axes, n, p3v_cap, rounds=splice_rounds,
-        interpret=interpret, block=block, batch=batch)
-    dist_sh, reach_sh = _rank_sharded(mate2_sh, axes, n,
-                                      interpret=interpret, block=block,
-                                      batch=batch)
+        mate_sh, sv_sh, axes, n, p3v_cap, rounds=splice_rounds)
+    dist_sh, reach_sh = _rank_sharded(mate2_sh, axes, n)
     if not gather_circuit:
         return mate2_sh, dist_sh, reach_sh, ok
     packed = jnp.stack([mate2_sh, dist_sh, reach_sh], axis=1)   # [S, 3]

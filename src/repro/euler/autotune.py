@@ -169,7 +169,11 @@ class CompileService:
     the drain-ordering tests use to make scheduling deterministic.
 
     Compile errors are isolated per ticket (``ticket.error``); the worker
-    thread never dies from a failed compile.
+    thread never dies from a failed compile.  Failed tickets are also kept
+    on the service (:attr:`failed`), and :meth:`raise_failures` re-raises
+    the first one — the micro-batcher and the autotuner call it, so a
+    failed background compile stops the serving loop instead of leaving
+    it at B=1.
     """
 
     def __init__(self, solver, start: bool = True):
@@ -184,6 +188,7 @@ class CompileService:
         self._thread: Optional[threading.Thread] = None
         self._stopped = False
         self.prewarms = 0               # programs actually compiled here
+        self.failed: List[CompileTicket] = []   # tickets whose job raised
         # job lifecycle observability (queued → compiling → landed /
         # failed): spans into the solver's trace log, state-labeled
         # counters into its registry; unit-test fake solvers fall back
@@ -239,6 +244,16 @@ class CompileService:
     def pending_jobs(self) -> int:
         with self._lock:
             return len(self._pending)
+
+    def raise_failures(self) -> None:
+        """Raise the first failed job's error, if any job has failed."""
+        with self._lock:
+            first = self.failed[0] if self.failed else None
+            n = len(self.failed)
+        if first is not None:
+            raise RuntimeError(
+                f"{n} background compile job(s) failed; first: "
+                f"{first.label}") from first.error
 
     # -- submission --------------------------------------------------------
 
@@ -319,6 +334,8 @@ class CompileService:
                 state="failed" if ticket.error else "landed").inc()
             with self._lock:
                 self._pending.pop(jkey, None)
+                if ticket.error is not None:
+                    self.failed.append(ticket)
                 self.prewarms += len(ticket.widths)
                 self._busy -= 1
                 if self._busy == 0:
@@ -553,7 +570,10 @@ class AutoTuner:
 
     def step(self, force: bool = False) -> Optional[Decision]:
         """Run one rate-limited policy step; returns the applied
-        :class:`Decision` (or None when skipped by the rate limit)."""
+        :class:`Decision` (or None when skipped by the rate limit).
+        Raises if a compile job the service ran has failed, whether or
+        not the rate limit lets this step run."""
+        self.service.raise_failures()
         now = self.clock()
         with self._lock:
             if not force and self._last_step is not None and \

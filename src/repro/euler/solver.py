@@ -514,15 +514,14 @@ class EulerSolver:
 
     def _program_cost(self, key: BucketKey, batch: Optional[int]) -> int:
         """Modeled device bytes of one cached program (the audit's static
-        cost model); 0 when the key is not a real bucket key (unit-test
-        fakes) or the analysis layer is unavailable."""
-        try:
-            from ..analysis.jaxpr_audit import program_cost_bytes
-
-            return int(program_cost_bytes(key, batch,
-                                          sharded=self.sharded_phase3))
-        except Exception:
+        cost model); 0 when the key is not a real 4-field bucket key (the
+        unit tests' stand-in keys)."""
+        if not (isinstance(key, tuple) and len(key) == 4):
             return 0
+        from ..analysis.jaxpr_audit import program_cost_bytes
+
+        return int(program_cost_bytes(key, batch,
+                                      sharded=self.sharded_phase3))
 
     def _evict_entry(self, pkey) -> None:
         """Drop one (bucket, B) program — LRU entry, modeled bytes, pin

@@ -32,12 +32,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, causal: bool, q_block: int,
 
     def body(j, carry):
         acc, m, l = carry
-        # leading dim via dslice(0, 1) + squeeze: older pallas versions
-        # don't normalize bare-int indices in pl.load
-        k = pl.load(k_ref, (pl.dslice(0, 1),
-                            pl.dslice(j * k_block, k_block), slice(None)))[0]
-        v = pl.load(v_ref, (pl.dslice(0, 1),
-                            pl.dslice(j * k_block, k_block), slice(None)))[0]
+        k = k_ref[0, pl.ds(j * k_block, k_block), :]
+        v = v_ref[0, pl.ds(j * k_block, k_block), :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 
 from . import ref
 from .flash_attention import flash_attention as _flash
-from .pointer_double import pointer_double as _pdouble
 from .segment_reduce import segment_sum_sorted as _segsum
 
 _ON_TPU = None
@@ -38,16 +37,6 @@ def segment_sum_sorted(values, seg_ids, num_segments: int,
     if use_kernel:
         return _segsum(values, seg_ids, num_segments, interpret=interpret)
     return ref.segment_sum_sorted_ref(values, seg_ids, num_segments)
-
-
-@partial(jax.jit, static_argnames=("use_kernel",))
-def pointer_double(nxt, lab, use_kernel: Optional[bool] = None):
-    """One pointer-doubling round."""
-    if use_kernel is None:
-        use_kernel = on_tpu()
-    if use_kernel:
-        return _pdouble(nxt, lab, interpret=not on_tpu())
-    return ref.pointer_double_ref(nxt, lab)
 
 
 @partial(jax.jit, static_argnames=("causal", "use_kernel"))

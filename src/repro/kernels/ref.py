@@ -1,4 +1,10 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose targets)."""
+"""Pure-jnp oracles for the Pallas kernels (the allclose targets), and the
+pointer-doubling rounds of the Euler engine's Phase 3.
+
+The doubling rounds are the only implementation on every backend: each is
+a whole-table XLA gather.  Mosaic lowers only same-shape 2-D gathers inside
+one vreg, so a lookup into an N-entry jump table has no Pallas TPU form.
+"""
 from __future__ import annotations
 
 import jax

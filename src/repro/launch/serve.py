@@ -173,10 +173,19 @@ class MicroBatcher:
         self._g_depth.set(sum(len(q) for q in self.pending.values()))
         return out
 
+    def _raise_compile_failures(self):
+        """A failed background compile (prewarm or autotuner job) is an
+        error of the serving loop, not a silent fall back to B=1."""
+        svc = getattr(self.solver, "compile_service", None)
+        if svc is not None:
+            svc.raise_failures()
+
     # -- public interface ----------------------------------------------
     def submit(self, seq: int, graph):
         """Queue one request; returns any results completed by the
-        pipeline, plus this bucket's flush if the submission filled it."""
+        pipeline, plus this bucket's flush if the submission filled it.
+        Raises if a background compile of this solver has failed."""
+        self._raise_compile_failures()
         key = self.solver.bucket_of(graph)
         if self.autotuner is not None:
             self.autotuner.observe_arrival(key, graph)
@@ -190,6 +199,7 @@ class MicroBatcher:
     def poll(self):
         """Flush every bucket whose oldest request passed the deadline;
         deliver whatever the pipeline has completed."""
+        self._raise_compile_failures()
         now = self.clock()
         due = [k for k, q in self.pending.items()
                if q and now - q[0][2] >= self.deadline_s]
@@ -212,6 +222,7 @@ class MicroBatcher:
         for k in list(self.pending):
             out.extend(self._flush(k))
         out.extend(self._harvest(block=True))
+        self._raise_compile_failures()
         return sorted(out)
 
 
@@ -285,6 +296,9 @@ def main_euler(argv=None):
 
     import threading
 
+    from .compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     import jax
 
     from ..euler import EulerSolver
@@ -341,6 +355,7 @@ def main_euler(argv=None):
 
     tuner = None
     rep: dict = {}
+    prewarm_errors: list = []     # exceptions of the detached prewarm
     if args.adaptive:
         # Adaptive warm path (DESIGN.md §12): no cold sweep, no static
         # prewarm — requests are served from the first arrival and the
@@ -372,6 +387,13 @@ def main_euler(argv=None):
         t0 = time.perf_counter()
         if max_batch > 1 and not args.eager and not args.no_prewarm:
             ladder_widths = [w for w in widths if w > 1]
+            def prewarm_all():
+                try:
+                    for g in rep.values():
+                        solver.prewarm(g, ladder_widths)
+                except BaseException as exc:  # noqa: BLE001 - re-raised
+                    prewarm_errors.append(exc)  # by main_euler at exit
+
             # thread-contract: daemon (never blocks interpreter exit;
             # prewarm holds no external resources and its work is safely
             # abandoned mid-compile).  Joined before the measured loop
@@ -379,11 +401,10 @@ def main_euler(argv=None):
             # compiles would skew the series; accelerator backends
             # compile in XLA worker threads, so the thread detaches and
             # the ladder warms behind live traffic — the batcher
-            # dispatches only to already-warm widths either way.
-            pw = threading.Thread(
-                target=lambda: [solver.prewarm(g, ladder_widths)
-                                for g in rep.values()],
-                name="prewarm", daemon=True)
+            # dispatches only to already-warm widths either way.  An
+            # exception it records is raised when serving ends.
+            pw = threading.Thread(target=prewarm_all, name="prewarm",
+                                  daemon=True)
             pw.start()
             if args.sync_prewarm or jax.default_backend() == "cpu":
                 pw.join()
@@ -445,6 +466,9 @@ def main_euler(argv=None):
     if tuner is not None:
         tuner_stats = tuner.stats()
         tuner.close(timeout=5.0)
+    if prewarm_errors:
+        raise RuntimeError("background width prewarm failed") \
+            from prewarm_errors[0]
 
     cs = solver.cache_stats
     thr = served / max(elapsed, 1e-9)

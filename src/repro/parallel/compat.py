@@ -1,10 +1,7 @@
-"""JAX version compatibility for the manual-collectives layer.
+"""Mesh and shard_map helpers for the manual-collectives layer.
 
-The codebase targets the modern API (``jax.shard_map`` with ``check_vma``,
-``jax.make_mesh(..., axis_types=...)``); older installs (≤ 0.4.x) expose
-``jax.experimental.shard_map.shard_map`` with ``check_rep`` and a
-``make_mesh`` without ``axis_types``.  Everything that builds meshes or
-shard_maps goes through these two helpers so one tree runs on both.
+Everything that builds meshes or shard_maps goes through these two
+helpers, so replication checking and axis types are set in one place.
 """
 from __future__ import annotations
 
@@ -14,28 +11,13 @@ import jax
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any jax."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types where supported; builds the
-    Mesh from ``mesh_utils`` on versions predating ``jax.make_mesh``."""
-    shapes, names = tuple(axis_shapes), tuple(axis_names)
-    if hasattr(jax, "make_mesh"):
-        try:
-            return jax.make_mesh(
-                shapes, names,
-                axis_types=(jax.sharding.AxisType.Auto,) * len(names),
-            )
-        except (AttributeError, TypeError):
-            return jax.make_mesh(shapes, names)
-    from jax.experimental import mesh_utils
-
-    return jax.sharding.Mesh(mesh_utils.create_device_mesh(shapes), names)
+    """``jax.make_mesh`` with Auto axis types."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))

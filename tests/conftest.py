@@ -28,8 +28,10 @@ except ImportError:  # the shim needs no profile — it is always seeded
 
 def run_with_devices(code: str, n: int = 8, timeout: int = 900) -> str:
     """Run ``code`` in a subprocess with ``n`` fake CPU devices (the main
-    test process must keep the default single device)."""
+    test process must keep the default single device).  The child is held
+    to the CPU: an accelerator belongs to one process, the parent."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
     src = os.path.join(REPO, "src")
     env["PYTHONPATH"] = src + (

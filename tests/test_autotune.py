@@ -138,6 +138,48 @@ def test_compile_service_isolates_job_errors():
     svc.stop()
 
 
+def test_failed_compile_job_stops_batcher_and_tuner():
+    # a failed background compile is an error of the serving loop, not a
+    # silent fall back to B=1
+    solver = _SvcSolver()
+    svc = CompileService(solver, start=False)
+    solver.compile_service = svc
+    batcher = MicroBatcher(solver, max_batch=4, deadline_s=60.0)
+    tuner = AutoTuner(solver, service=svc, max_batch=4)
+    assert batcher.poll() == [] and batcher.drain() == []
+    bad = svc.submit("boom", 2)
+    svc.start()
+    assert svc.join(timeout=30) and bad.error is not None
+    assert svc.failed == [bad]
+    with pytest.raises(RuntimeError, match="background compile") as ei:
+        batcher.submit(1, "a")
+    assert isinstance(ei.value.__cause__, RuntimeError)
+    with pytest.raises(RuntimeError, match="background compile"):
+        batcher.poll()
+    with pytest.raises(RuntimeError, match="background compile"):
+        batcher.drain()
+    with pytest.raises(RuntimeError, match="background compile"):
+        tuner.step(force=True)
+    svc.stop()
+
+
+def test_main_euler_raises_when_detached_prewarm_fails(monkeypatch,
+                                                       tmp_path):
+    from repro.launch import serve
+
+    def boom(self, graph, widths=None):
+        raise RuntimeError("prewarm exploded")
+
+    monkeypatch.setattr(EulerSolver, "prewarm", boom)
+    # JAX reads the variable only at start-up: the helper places nothing
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    with pytest.raises(RuntimeError, match="prewarm failed") as ei:
+        serve.main_euler(["--scale", "5", "--parts", "1", "--pool", "1",
+                          "--requests", "2", "--max-batch", "2",
+                          "--widths", "1,2", "--sync-prewarm"])
+    assert "prewarm exploded" in str(ei.value.__cause__)
+
+
 # ---------------------------------------------------------------------------
 # the pure policy: deterministic histogram fixtures → expected orders
 # ---------------------------------------------------------------------------

@@ -1,4 +1,5 @@
-"""Per-kernel allclose sweeps (interpret mode) against the ref.py oracles."""
+"""Per-kernel allclose sweeps (interpret mode) against the ref.py oracles,
+and the Phase 3 doubling rounds of ref.py against NumPy."""
 import numpy as np
 import pytest
 
@@ -7,9 +8,6 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.pointer_double import (pointer_double,
-                                          pointer_double_rank,
-                                          resolve_interpret)
 from repro.kernels.segment_reduce import segment_sum_sorted
 
 
@@ -44,48 +42,40 @@ def test_segment_sum_with_padding_ids():
     np.testing.assert_allclose(out_k, out_r, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("N,block", [(1024, 256), (4096, 2048), (8192, 512)])
-def test_pointer_double_sweep(N, block):
+def _np_double(nxt, lab):
+    return nxt[nxt], np.minimum(lab, lab[nxt])
+
+
+def _np_double_rank(ptr, dist, reach):
+    return ptr[ptr], dist + dist[ptr], np.maximum(reach, reach[ptr])
+
+
+@pytest.mark.parametrize("N", [1024, 4096, 8192])
+def test_pointer_double_sweep(N):
+    """One XLA doubling round (Phase 3's CC gather) matches NumPy."""
     rng = np.random.default_rng(N)
     nxt = rng.integers(0, N, N).astype(np.int32)
     lab = rng.permutation(N).astype(np.int32)
-    nk, lk = pointer_double(jnp.asarray(nxt), jnp.asarray(lab), block=block,
-                            interpret=True)
-    nr, lr = ref.pointer_double_ref(jnp.asarray(nxt), jnp.asarray(lab))
-    assert (np.asarray(nk) == np.asarray(nr)).all()
-    assert (np.asarray(lk) == np.asarray(lr)).all()
+    nk, lk = jax.jit(ref.pointer_double_ref)(jnp.asarray(nxt),
+                                              jnp.asarray(lab))
+    nr, lr = _np_double(nxt, lab)
+    assert (np.asarray(nk) == nr).all()
+    assert (np.asarray(lk) == lr).all()
 
 
 def test_pointer_double_converges_on_cycle():
-    """log₂ N rounds of the kernel label a single cycle uniformly."""
+    """log₂ N doubling rounds label a single cycle uniformly."""
     N = 512
     nxt = jnp.asarray((np.arange(N) + 1) % N, jnp.int32)
     lab = jnp.asarray(np.arange(N), jnp.int32)
     for _ in range(int(np.ceil(np.log2(N))) + 1):
-        nxt, lab = pointer_double(nxt, lab, interpret=True)
+        nxt, lab = ref.pointer_double_ref(nxt, lab)
     assert int(jnp.max(lab)) == 0
 
 
-def test_pointer_double_platform_autodetect():
-    """interpret=None resolves by backend: compiled only on TPU."""
-    expect = jax.default_backend() != "tpu"
-    assert resolve_interpret(None) is expect
-    assert resolve_interpret(True) is True
-    assert resolve_interpret(False) is False
-    # the default path must run (and agree with the oracle) on any backend
-    rng = np.random.default_rng(0)
-    N = 1024
-    nxt = jnp.asarray(rng.integers(0, N, N), jnp.int32)
-    lab = jnp.asarray(rng.permutation(N), jnp.int32)
-    nk, lk = pointer_double(nxt, lab)
-    nr, lr = ref.pointer_double_ref(nxt, lab)
-    assert (np.asarray(nk) == np.asarray(nr)).all()
-    assert (np.asarray(lk) == np.asarray(lr)).all()
-
-
-@pytest.mark.parametrize("N,block", [(1024, 256), (4096, 2048), (8192, 512)])
-def test_pointer_double_rank_sweep(N, block):
-    """The list-ranking kernel matches the pure-jnp doubling round."""
+@pytest.mark.parametrize("N", [1024, 4096, 8192])
+def test_pointer_double_rank_sweep(N):
+    """One XLA list-ranking round matches NumPy."""
     rng = np.random.default_rng(N + 1)
     ptr = rng.integers(0, N, N).astype(np.int32)
     t = int(ptr[0])
@@ -94,19 +84,16 @@ def test_pointer_double_rank_sweep(N, block):
     dist[t] = 0
     reach = np.zeros(N, np.int32)
     reach[t] = 1
-    pk, dk, rk = pointer_double_rank(jnp.asarray(ptr), jnp.asarray(dist),
-                                     jnp.asarray(reach), block=block,
-                                     interpret=True)
-    pr, dr, rr = ref.pointer_double_rank_ref(jnp.asarray(ptr),
-                                             jnp.asarray(dist),
-                                             jnp.asarray(reach))
-    assert (np.asarray(pk) == np.asarray(pr)).all()
-    assert (np.asarray(dk) == np.asarray(dr)).all()
-    assert (np.asarray(rk) == np.asarray(rr)).all()
+    pk, dk, rk = jax.jit(ref.pointer_double_rank_ref)(
+        jnp.asarray(ptr), jnp.asarray(dist), jnp.asarray(reach))
+    pr, dr, rr = _np_double_rank(ptr, dist, reach)
+    assert (np.asarray(pk) == pr).all()
+    assert (np.asarray(dk) == dr).all()
+    assert (np.asarray(rk) == rr).all()
 
 
 def test_pointer_double_rank_ranks_a_list():
-    """Doubling rounds of the rank kernel compute list ranks on a chain."""
+    """Doubling rounds of the rank gather compute list ranks on a chain."""
     N = 256
     ptr = np.minimum(np.arange(N) + 1, N - 1).astype(np.int32)  # i → i+1
     dist = np.ones(N, np.int32)
@@ -115,7 +102,7 @@ def test_pointer_double_rank_ranks_a_list():
     reach[N - 1] = 1
     p, d, r = jnp.asarray(ptr), jnp.asarray(dist), jnp.asarray(reach)
     for _ in range(int(np.ceil(np.log2(N))) + 1):
-        p, d, r = pointer_double_rank(p, d, r, interpret=True)
+        p, d, r = ref.pointer_double_rank_ref(p, d, r)
     assert (np.asarray(r) == 1).all()
     # dist[i] = hops from i to the tail
     assert (np.asarray(d) == (N - 1 - np.arange(N))).all()

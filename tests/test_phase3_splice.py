@@ -151,15 +151,15 @@ def test_phase3_device_end_to_end():
 
 
 def test_circuit_pallas_backend_byte_identical():
-    """The Pallas pointer_double_rank backend of circuit_from_mate_jnp is
-    bit-identical to the pure-jnp doubling loop."""
+    """The XLA doubling rounds of circuit_from_mate_jnp (the backend that
+    replaced the Pallas kernel) emit the NumPy list-ranking oracle's
+    circuit byte for byte."""
     g, mate = graph_of_cycles(7, [[0, 1, 2], [0, 3, 4], [0, 5, 6]])
     sv = stub_vertices(g)
     m = splice_components_np(mate.copy(), sv, mate >= 0)
-    start = jnp.int32(int(m[0]) ^ 1)
-    c_jnp = circuit_from_mate_jnp(jnp.asarray(m, jnp.int32), start,
-                                  use_pallas=False)
-    c_pal = circuit_from_mate_jnp(jnp.asarray(m, jnp.int32), start,
-                                  use_pallas=True)
-    assert (np.asarray(c_jnp) == np.asarray(c_pal)).all()
-    validate_circuit(g, np.asarray(c_pal, dtype=np.int64))
+    start = int(m[0]) ^ 1
+    c_np = circuit_from_mate_np(m, start)
+    c_jnp = circuit_from_mate_jnp(jnp.asarray(m, jnp.int32),
+                                  jnp.int32(start))
+    assert (np.asarray(c_jnp) == c_np).all()
+    validate_circuit(g, np.asarray(c_jnp, dtype=np.int64))

@@ -112,9 +112,8 @@ def random_cycle_cover(n_vertices, n_trails, trail_len):
 
 def check(mate, sv, E, n, label):
     n_stubs = 2 * E
-    c_rep, m_rep, ok_rep = jax.jit(
-        lambda m, s: phase3_device(m, s, interpret=True))(
-            jnp.asarray(mate), jnp.asarray(sv))
+    c_rep, m_rep, ok_rep = jax.jit(phase3_device)(
+        jnp.asarray(mate), jnp.asarray(sv))
     assert bool(ok_rep), f"{label}: replicated did not converge"
 
     S = shard_width(E, n)
@@ -127,8 +126,7 @@ def check(mate, sv, E, n, label):
     p3v = int(max(np.bincount(owners, weights=deg, minlength=n))) + 8
 
     def f(m_sh, s_sh):
-        return phase3_sharded(m_sh, s_sh, "x", n, n_stubs, p3v,
-                              interpret=True)
+        return phase3_sharded(m_sh, s_sh, "x", n, n_stubs, p3v)
 
     with mesh:
         fn = jax.jit(shard_map(f, mesh, (P("x"), P("x")),
