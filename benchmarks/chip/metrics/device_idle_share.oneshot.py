@@ -1,0 +1,8 @@
+"""Share of the traced window in which no op ran on the busiest chip:
+100 * (1 - busy / window), busy being the union of its op intervals."""
+
+
+def read(ctx):
+    if ctx.trace is None or ctx.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - ctx.trace.max_busy_s / ctx.trace.window_s)
