@@ -143,7 +143,8 @@ class StepOut(NamedTuple):
     log_s2: jnp.ndarray
     log_mask: jnp.ndarray
     flags: jnp.ndarray     # [n, 4] cc, splice, p1-overflow, ship-overflow
-    metrics: jnp.ndarray   # [n, 4] longs: remote, opens, touch, comps
+    metrics: jnp.ndarray   # [n, 6] longs: remote, opens, touch, comps;
+                           #        Phase 1 hook and splice rounds run
 
 
 class FusedOut(NamedTuple):
@@ -160,8 +161,9 @@ class FusedOut(NamedTuple):
     circuit: jnp.ndarray   # [E] arrival stubs in walk order (replicated)
     mate: jnp.ndarray      # [2E] post-splice mate permutation (replicated)
     flags: jnp.ndarray     # [n, L, 4]
-    metrics: jnp.ndarray   # [n, L, 4]
+    metrics: jnp.ndarray   # [n, L, 6] (``StepOut.metrics`` per level)
     phase3_ok: jnp.ndarray  # [] bool: pivot splice converged
+    phase3_rounds: jnp.ndarray  # [] int32: pivot-splice rounds run
 
 
 class PendingRun:
@@ -169,12 +171,12 @@ class PendingRun:
     asynchronously, nothing has been fetched yet.
 
     ``ready()`` is a non-blocking completion probe (the circuit buffer is
-    only materialized once the whole program finishes); ``wait()``
-    performs the run's ONE device→host sync and builds the per-graph
-    results.  The serving pipeline holds these to overlap host-side prep
-    of the next flush with device execution of the current one
-    (DESIGN.md §9); ``_run``/``_run_batch`` are dispatch→wait with no
-    overlap.
+    only materialized once the whole program finishes); ``sync()``
+    performs the run's ONE device→host sync (the ``wait`` span) and
+    ``wait()`` builds the per-graph results from what it fetched.  The
+    serving pipeline holds these to overlap host-side prep of the next
+    flush with device execution of the current one (DESIGN.md §9);
+    ``_run``/``_run_batch`` are dispatch→wait with no overlap.
     """
 
     def __init__(self, engine: "DistributedEngine", out: FusedOut,
@@ -186,32 +188,41 @@ class PendingRun:
         self.trees = trees
         self.t0 = t0
         self.batch = batch              # None → single-graph program
+        self._host = None               # the fetched outputs (sync())
+        self._run_s = 0.0
         self._results = None
 
     def ready(self) -> bool:
-        if self._results is not None:
+        if self._results is not None or self._host is not None:
             return True
         probe = getattr(self.out.circuit, "is_ready", None)
         return bool(probe()) if probe is not None else True
 
+    def sync(self) -> None:
+        """Block until the device run completes and fetch every output
+        with ONE ``device_get`` (the run's single device→host sync)."""
+        if self._host is not None or self._results is not None:
+            return
+        with self.engine.trace.span("wait", width=self.batch or 1):
+            self._host = jax.device_get(tuple(self.out))
+        self.out = None                 # free the device buffers
+        self._run_s = time.perf_counter() - self.t0
+
     def wait(self):
         """Block until the device run completes; returns one
-        :class:`repro.euler.result.EulerResult` per graph (the fetch is
-        the run's single device→host sync)."""
+        :class:`repro.euler.result.EulerResult` per graph, built from
+        :meth:`sync`'s one fetch."""
         if self._results is not None:
             return self._results
         from ..euler.result import EulerResult
 
-        out = self.out
-        with self.engine.trace.span("wait", width=self.batch or 1):
-            circuit, mate, flags, metrics, ok3 = jax.device_get(
-                (out.circuit, out.mate, out.flags, out.metrics,
-                 out.phase3_ok)
-            )
-        self.out = None                 # free the device buffers
-        run_s = time.perf_counter() - self.t0
+        self.sync()
+        circuit, mate, flags, metrics, ok3, rounds3 = self._host
+        self._host = None
+        run_s = self._run_s
         if self.batch is None:          # unify to batched layouts
             circuit, mate, ok3 = circuit[None], mate[None], ok3[None]
+            rounds3 = rounds3[None]
             flags, metrics = flags[:, None], metrics[:, None]
         if self.engine.sharded_phase3 and not self.engine.gather_circuit:
             # gather_circuit=False: the program returned the rank triple
@@ -226,7 +237,8 @@ class PendingRun:
                                 packed[b, :, 2])
                 for b in range(mate.shape[0])
             ])
-        # circuit [B, E], mate [B, 2E], flags/metrics [n, B, L, 4], ok3 [B]
+        # circuit [B, E], mate [B, 2E], flags [n, B, L, 4],
+        # metrics [n, B, L, 6], ok3 / rounds3 [B]
         if not flags.all():
             raise RuntimeError(
                 f"convergence/capacity flags failed: {flags.all((0, 2, 3))}"
@@ -251,7 +263,7 @@ class PendingRun:
                 levels=EulerResult.levels_from_metrics(metrics_list),
                 supersteps=n_levels, backend="device", fused=True,
                 graph=pg.graph, phase3_converged=bool(ok3[b]),
-                timings=timings,
+                phase3_rounds=int(rounds3[b]), timings=timings,
             ))
         self._results = results
         return results
@@ -788,7 +800,8 @@ class DistributedEngine:
                 [2 * jnp.sum(pm).astype(I32),
                  3 * jnp.sum(out.opens.mask).astype(I32),
                  4 * jnp.sum(out.touch.mask).astype(I32),
-                 4 * out.n_components]
+                 4 * out.n_components,
+                 out.hook_rounds, out.splice_rounds]
             )
             return nstate, out.log_s1, out.log_s2, out.log_mask, flags, metrics
 
@@ -855,8 +868,14 @@ class DistributedEngine:
             splice + list-rank emission (``phase3_device``), replicated
             per device, XLA gather rounds as the doubling backend.
 
-        The program's outputs (circuit, mate, flags, metrics) are fetched
-        with ONE host transfer in :meth:`run`.
+        The program's outputs (circuit, mate, flags, metrics with the
+        per-level loop rounds, Phase 3's convergence flag and rounds) are
+        fetched with ONE host transfer (:meth:`PendingRun.sync`).  Its
+        phases carry ``jax.named_scope``s, which reach the HLO metadata
+        (``op_name``) and so a profile: ``phase1`` (the level scan),
+        ``merge_exchange`` (the mate writes' ``_route`` + ``all_to_all``,
+        inside the scan) and ``phase3`` (with ``cc``, ``splice``, ``rank``
+        and ``emit`` inside it); an op belongs to its innermost scope.
 
         ``batch=B`` builds the *batched* program (DESIGN.md §8): every
         per-graph input grows a leading batch axis *after* the partition
@@ -899,53 +918,54 @@ class DistributedEngine:
                 nstate, s1, s2, lm, flags, metrics = core(lvl, anc, st)
                 # mate writes: both directions of every logged pair, routed
                 # to the stub's owning shard
-                ws = jnp.concatenate([s1, s2])
-                wv = jnp.concatenate([s2, s1])
-                wm = jnp.concatenate([lm, lm])
-                dest = jnp.where(wm, ws // S, n)
-                (bs, bv), bm, of_m = _route(dest, wm, (ws, wv), n, wcap)
-                r_s = jax.lax.all_to_all(bs, axes, 0, 0, tiled=True).reshape(-1)
-                r_v = jax.lax.all_to_all(bv, axes, 0, 0, tiled=True).reshape(-1)
-                r_m = jax.lax.all_to_all(bm, axes, 0, 0, tiled=True).reshape(-1)
-                off = jnp.where(r_m, r_s - me * S, S)   # masked → pad slot
-                mate_sh = mate_sh.at[off].set(jnp.where(r_m, r_v, -1))
-                flags = flags.at[3].set(flags[3] & ~of_m)
+                with jax.named_scope("merge_exchange"):
+                    ws = jnp.concatenate([s1, s2])
+                    wv = jnp.concatenate([s2, s1])
+                    wm = jnp.concatenate([lm, lm])
+                    dest = jnp.where(wm, ws // S, n)
+                    (bs, bv), bm, of_m = _route(dest, wm, (ws, wv), n, wcap)
+                    r_s, r_v, r_m = [
+                        jax.lax.all_to_all(b, axes, 0, 0,
+                                           tiled=True).reshape(-1)
+                        for b in (bs, bv, bm)]
+                    off = jnp.where(r_m, r_s - me * S, S)  # masked → pad
+                    mate_sh = mate_sh.at[off].set(jnp.where(r_m, r_v, -1))
+                    flags = flags.at[3].set(flags[3] & ~of_m)
                 return (nstate, mate_sh), (flags, metrics)
 
-            mate0 = jnp.full((S + 1,), -1, dtype=I32)
-            (state, mate_sh), (flags, metrics) = jax.lax.scan(
-                body, (state, mate0), jnp.arange(L, dtype=I32)
-            )
-            if not sharded:
-                mate = jax.lax.all_gather(mate_sh[:S], axes,
-                                          tiled=True)[:n_stubs]
-                circuit, mate2, ok3 = phase3_device(
-                    mate, sv, splice_rounds=c.phase3_rounds)
-                return circuit, mate2, flags, metrics, ok3
-            # DESIGN.md §11: Phase 3 runs on the accumulator shards
-            # directly — no mate all_gather; sv arrives sharded too.
-            res3 = phase3_sharded(
-                mate_sh[:S], sv, axes, n, n_stubs, p3v,
-                splice_rounds=c.phase3_rounds, gather_circuit=gather)
-            if gather:
-                circuit, mate2, ok3 = res3
-                return circuit, mate2, flags, metrics, ok3
-            m2_sh, dist_sh, reach_sh, ok3 = res3
-            packed = jnp.stack([m2_sh, dist_sh, reach_sh], axis=1)  # [S,3]
-            return packed, m2_sh, flags, metrics, ok3
+            with jax.named_scope("phase1"):
+                mate0 = jnp.full((S + 1,), -1, dtype=I32)
+                (state, mate_sh), (flags, metrics) = jax.lax.scan(
+                    body, (state, mate0), jnp.arange(L, dtype=I32)
+                )
+            with jax.named_scope("phase3"):
+                if not sharded:
+                    mate = jax.lax.all_gather(mate_sh[:S], axes,
+                                              tiled=True)[:n_stubs]
+                    circuit, mate2, ok3, r3 = phase3_device(
+                        mate, sv, splice_rounds=c.phase3_rounds)
+                    return circuit, mate2, flags, metrics, ok3, r3
+                # DESIGN.md §11: Phase 3 runs on the accumulator shards
+                # directly — no mate all_gather; sv arrives sharded too.
+                res3 = phase3_sharded(
+                    mate_sh[:S], sv, axes, n, n_stubs, p3v,
+                    splice_rounds=c.phase3_rounds, gather_circuit=gather)
+                if gather:
+                    circuit, mate2, ok3, r3 = res3
+                    return circuit, mate2, flags, metrics, ok3, r3
+                m2_sh, dist_sh, reach_sh, ok3, r3 = res3
+                packed = jnp.stack([m2_sh, dist_sh, reach_sh], axis=1)
+                return packed, m2_sh, flags, metrics, ok3, r3  # [S,3]
 
         def device_fn(anc, state: EngineState, sv) -> FusedOut:
             state = jax.tree.map(lambda x: x[0], state)  # [1,·] → [·]
-            if batch is None:
-                circuit, mate2, flags, metrics, ok3 = one_graph(
-                    anc, state, sv)
-            else:
-                circuit, mate2, flags, metrics, ok3 = jax.vmap(one_graph)(
-                    anc, state, sv)
+            run = one_graph if batch is None else jax.vmap(one_graph)
+            circuit, mate2, flags, metrics, ok3, r3 = run(anc, state, sv)
+            with jax.named_scope("phase1"):     # the level scan's outputs
+                flags, metrics = flags[None], metrics[None]
             return FusedOut(
-                circuit=circuit, mate=mate2,
-                flags=flags[None], metrics=metrics[None],
-                phase3_ok=ok3,
+                circuit=circuit, mate=mate2, flags=flags, metrics=metrics,
+                phase3_ok=ok3, phase3_rounds=r3,
             )
 
         state_specs = self._state_specs()
@@ -964,7 +984,7 @@ class DistributedEngine:
         out_specs = FusedOut(
             circuit=circuit_spec, mate=mate_spec,
             flags=P(axes, None, None), metrics=P(axes, None, None),
-            phase3_ok=P(),
+            phase3_ok=P(), phase3_rounds=P(),
         )
         fn = shard_map(
             device_fn,
@@ -1106,9 +1126,7 @@ class DistributedEngine:
                   resident: bool = True) -> PendingRun:
         """Dispatch ONE fused run asynchronously (stage + launch); no
         host sync happens until :meth:`PendingRun.wait`."""
-        t0 = time.perf_counter()
-        with self.trace.span("dispatch", edges=pg.graph.num_edges):
-            return self._launch(self._stage(pg, resident=resident), t0)
+        return self._launch(self._stage(pg, resident=resident))
 
     def evict_program(self, num_edges: int, batch: Optional[int]) -> int:
         """Drop the compiled fused program(s) for ``(num_edges, batch)``
@@ -1192,7 +1210,7 @@ class DistributedEngine:
             mate[s2[keep]] = s1[keep]
         if not (mate >= 0).all():
             raise RuntimeError(f"{(mate < 0).sum()} stubs unmated")
-        circuit_j, mate2_j, ok3 = self._phase3_prog()(
+        circuit_j, mate2_j, ok3, rounds3 = self._phase3_prog()(
             jnp.asarray(mate, dtype=I32), jnp.asarray(sv, dtype=I32)
         )
         if not bool(ok3):
@@ -1205,6 +1223,7 @@ class DistributedEngine:
             tree=self.tree, levels=EulerResult.levels_from_metrics(metrics),
             supersteps=self.n_levels, backend="device", fused=False,
             graph=pg.graph, phase3_converged=bool(ok3),
+            phase3_rounds=int(rounds3),
             timings={"run_s": time.perf_counter() - t0},
         )
 
@@ -1220,46 +1239,47 @@ class DistributedEngine:
         """
         if not pgs:
             raise ValueError("empty batch")
-        E = pgs[0].graph.num_edges
-        B = len(pgs)
-        bkey = tuple(id(pg) for pg in pgs)
-        bent = self._batch_cache.get(bkey)
-        if bent is not None and all(a is b for a, b in zip(bent["pgs"], pgs)):
-            anc, state, sv = bent["dev"]
-            trees = bent["trees"]
-            self._batch_cache[bkey] = self._batch_cache.pop(bkey)  # LRU touch
-        else:
-            states, ancs, svs, trees = [], [], [], []
-            for pg in pgs:
-                if pg.graph.num_edges != E:
-                    raise ValueError(
-                        f"mixed edge counts in batch: "
-                        f"{pg.graph.num_edges} != {E}")
-                ent = self._load_cached(pg)
-                states.append(ent["state"])
-                ancs.append(ent["anc"])
-                svs.append(ent["sv"])
-                trees.append(ent["tree"])
-            # stack along a batch axis AFTER the partition axis ([n, B, ·])
-            # on the host, then ship each field once — stacking device
-            # arrays instead would dispatch ~#fields × B tiny device ops
-            with self.trace.span("upload", edges=E, width=B):
-                state = jax.tree.map(
-                    lambda *xs: jnp.asarray(np.stack(xs, axis=1)), *states)
-                anc = jnp.asarray(np.stack(ancs))              # [B, H, n]
-                sv = jnp.asarray(
-                    np.stack([self._pad_sv(s) for s in svs]),
-                    dtype=I32)                     # [B, 2E]
-            if len(self._batch_cache) >= self._batch_cache_max:
-                self._batch_cache.pop(next(iter(self._batch_cache)))
-            self._batch_cache[bkey] = {
-                "pgs": list(pgs), "dev": (anc, state, sv), "trees": trees,
-            }
-            if self.on_upload is not None:
-                self.on_upload()
+        with self.trace.span("stage", width=len(pgs)):
+            E = pgs[0].graph.num_edges
+            B = len(pgs)
+            bkey = tuple(id(pg) for pg in pgs)
+            bent = self._batch_cache.get(bkey)
+            if bent is not None and all(a is b for a, b in zip(bent["pgs"], pgs)):
+                anc, state, sv = bent["dev"]
+                trees = bent["trees"]
+                self._batch_cache[bkey] = self._batch_cache.pop(bkey)  # LRU touch
+            else:
+                states, ancs, svs, trees = [], [], [], []
+                for pg in pgs:
+                    if pg.graph.num_edges != E:
+                        raise ValueError(
+                            f"mixed edge counts in batch: "
+                            f"{pg.graph.num_edges} != {E}")
+                    ent = self._load_cached(pg)
+                    states.append(ent["state"])
+                    ancs.append(ent["anc"])
+                    svs.append(ent["sv"])
+                    trees.append(ent["tree"])
+                # stack along a batch axis AFTER the partition axis ([n, B, ·])
+                # on the host, then ship each field once — stacking device
+                # arrays instead would dispatch ~#fields × B tiny device ops
+                with self.trace.span("upload", edges=E, width=B):
+                    state = jax.tree.map(
+                        lambda *xs: jnp.asarray(np.stack(xs, axis=1)), *states)
+                    anc = jnp.asarray(np.stack(ancs))              # [B, H, n]
+                    sv = jnp.asarray(
+                        np.stack([self._pad_sv(s) for s in svs]),
+                        dtype=I32)                     # [B, 2E]
+                if len(self._batch_cache) >= self._batch_cache_max:
+                    self._batch_cache.pop(next(iter(self._batch_cache)))
+                self._batch_cache[bkey] = {
+                    "pgs": list(pgs), "dev": (anc, state, sv), "trees": trees,
+                }
+                if self.on_upload is not None:
+                    self.on_upload()
 
-        prog = self.fused_program(E, batch=B)
-        return (prog, (anc, state, sv), False, list(pgs), trees, B)
+            prog = self.fused_program(E, batch=B)
+            return (prog, (anc, state, sv), False, list(pgs), trees, B)
 
     def _dispatch_batch(self, pgs: List[PartitionedGraph]) -> PendingRun:
         """Dispatch B same-shape runs as ONE batched fused program
@@ -1267,9 +1287,7 @@ class DistributedEngine:
         :meth:`PendingRun.wait` performs the single host sync and yields
         one :class:`repro.euler.result.EulerResult` per graph,
         byte-identical to B sequential :meth:`_run` calls."""
-        t0 = time.perf_counter()
-        with self.trace.span("dispatch", width=len(pgs)):
-            return self._launch(self._stage_batch(pgs), t0)
+        return self._launch(self._stage_batch(pgs))
 
     def _run_batch(self, pgs: List[PartitionedGraph]):
         """Synchronous wrapper: dispatch one batched fused run, then

@@ -21,7 +21,7 @@ the paper uses.  The *ideal* curve holds the level-0 average constant; the
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -38,6 +38,11 @@ class PartitionState:
     touch: int
     components: int
     deferred_remote: int = 0  # copies parked on this (inactive) leaf host
+    # device backend: Phase 1 loop rounds this partition ran at this
+    # level (hook/jump rounds of both CC calls; splice rounds); None on
+    # the host backend
+    hook_rounds: Optional[int] = None
+    splice_rounds: Optional[int] = None
 
     @property
     def longs(self) -> int:
@@ -65,6 +70,20 @@ class LevelStats:
     @property
     def cumulative(self) -> int:
         return sum(s.longs for s in self.states)
+
+    @property
+    def hook_rounds(self) -> Optional[List[int]]:
+        """Phase 1 hook/jump rounds per partition (None: host backend)."""
+        if any(s.hook_rounds is None for s in self.states):
+            return None
+        return [s.hook_rounds for s in self.states]
+
+    @property
+    def splice_rounds(self) -> Optional[List[int]]:
+        """Phase 1 splice rounds per partition (None: host backend)."""
+        if any(s.splice_rounds is None for s in self.states):
+            return None
+        return [s.splice_rounds for s in self.states]
 
     @property
     def average(self) -> float:

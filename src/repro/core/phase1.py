@@ -82,6 +82,8 @@ class Phase1Out(NamedTuple):
     log_mask: jnp.ndarray
     n_components: jnp.ndarray  # [] live components touching this partition
     flags: jnp.ndarray         # [3] bool: cc converged, splice converged, no overflow
+    hook_rounds: jnp.ndarray   # [] hook/jump rounds run, both CC calls
+    splice_rounds: jnp.ndarray  # [] splice rounds run
 
 
 def pair_table_cap(pool: int, touch_cap: int) -> int:
@@ -90,6 +92,26 @@ def pair_table_cap(pool: int, touch_cap: int) -> int:
     ``EngineCaps.pair_cap`` so the engine's mate-log lane sizing can never
     drift from the table the log is emitted from."""
     return pool // 2 + touch_cap
+
+
+def _hook_budgets(caps: Phase1Caps, pool: int, touch_cap: int):
+    """Round budgets of Phase 1's two hook/jump CC calls.  A nonzero
+    ``caps.hook_rounds`` fixes both; else each is ``ceil(log2 K) + 2``
+    for its universe of K values: the component values (``pool +
+    touch_cap``) for the first, twice as many for the second (that
+    universe joined with the pair table's post-splice comps)."""
+    if caps.hook_rounds:
+        return caps.hook_rounds, caps.hook_rounds
+    k = pool + touch_cap
+    return (int(math.ceil(math.log2(max(2, k)))) + 2,
+            int(math.ceil(math.log2(max(2, 2 * k)))) + 2)
+
+
+def hook_round_budget(caps: Phase1Caps, pool: int, touch_cap: int) -> int:
+    """Most hook/jump rounds one ``phase1_local`` call can report: the
+    sum of both CC calls' budgets (``pool`` = stub-pool width,
+    ``2·new_cap + open_cap``)."""
+    return sum(_hook_budgets(caps, pool, touch_cap))
 
 
 def empty_open(cap: int) -> OpenTable:
@@ -126,7 +148,7 @@ def _cc_hook_jump(ca, cb, emask, universe, rounds: int,
 
     Nodes are the values in ``universe`` ([K], BIG-padded); edges are
     (ca[i], cb[i]) where ``emask[i]``.  Returns (sorted universe,
-    root *value* per universe slot, converged flag).
+    root *value* per universe slot, converged flag, rounds run).
 
     Runs at most ``rounds`` hook/jump/contract rounds and stops at the
     first round that leaves ``(lab, ea, eb)`` unchanged: a round is a
@@ -162,6 +184,7 @@ def _cc_hook_jump(ca, cb, emask, universe, rounds: int,
     if static:
         for _ in range(rounds):
             lab, ea, eb = round_(lab, ea, eb)
+        ran = jnp.array(rounds, I32)
     else:
         def body(state):
             lab, ea, eb, _, r = state
@@ -170,11 +193,11 @@ def _cc_hook_jump(ca, cb, emask, universe, rounds: int,
                        | jnp.any(neb != eb))
             return nlab, nea, neb, changed, r + 1
 
-        lab, ea, eb, _, _ = jax.lax.while_loop(
+        lab, ea, eb, _, ran = jax.lax.while_loop(
             lambda st: st[3] & (st[4] < rounds), body,
             (lab, ea, eb, jnp.array(True), jnp.array(0, I32)))
     converged = jnp.all(hook(lab, ea, eb) == lab)
-    return uniq, uniq[lab], converged
+    return uniq, uniq[lab], converged, ran
 
 
 def _value_lookup(uniq, root_val, values):
@@ -213,6 +236,7 @@ def phase1_local(
     )
     pool_mask = jnp.concatenate([nm, nm, om])
     P = pool_stub.shape[0]
+    hook1, hook2 = _hook_budgets(caps, P, touch.mask.shape[0])
 
     # ------------------------------------------------------------------
     # 2. pair per vertex: sort by (vertex, stub), pair consecutive
@@ -242,10 +266,8 @@ def phase1_local(
     universe = jnp.concatenate(
         [jnp.where(sm, sc, BIG), jnp.where(touch.mask, touch.comp, BIG)]
     )
-    uniq, root_val, cc_ok = _cc_hook_jump(
-        pr_ca, pr_cb, pr_mask, universe,
-        caps.hook_rounds or int(math.ceil(math.log2(max(2, universe.shape[0])))) + 2,
-        static=caps.static_splice,
+    uniq, root_val, cc_ok, hook_ran = _cc_hook_jump(
+        pr_ca, pr_cb, pr_mask, universe, hook1, static=caps.static_splice,
     )
     open_comp = _value_lookup(uniq, root_val, jnp.where(left_mask, sc, BIG))
     pair_comp = _value_lookup(uniq, root_val, pr_ca)
@@ -354,11 +376,13 @@ def phase1_local(
             state = splice_round(state)
         q_s2, q_c, oc, still_changing, _ = state
         splice_ok = jnp.array(True)   # fixed rounds; flag checked by tests
+        splice_ran = jnp.array(caps.splice_rounds, I32)
     else:
-        q_s2, q_c, oc, still_changing, _ = jax.lax.while_loop(
+        q_s2, q_c, oc, still_changing, left = jax.lax.while_loop(
             cond, splice_round, init
         )
         splice_ok = ~still_changing
+        splice_ran = caps.splice_rounds - left
 
     # ------------------------------------------------------------------
     # 6. rebuild tables
@@ -367,13 +391,12 @@ def phase1_local(
     # (from → min of merged set), so CC over (pre-splice comp → final comp)
     # pairs has the final label as its min — a single hook/jump pass maps
     # every original comp to its final id.
-    uniq3, root3, cc3_ok = _cc_hook_jump(
+    uniq3, root3, cc3_ok, hook3_ran = _cc_hook_jump(
         q_c_pre,
         q_c,
         q_m,
         jnp.concatenate([universe, jnp.where(q_m, q_c, BIG)]),
-        caps.hook_rounds or int(
-            math.ceil(math.log2(max(2, 2 * universe.shape[0])))) + 2,
+        hook2,
         static=caps.static_splice,
     )
     open_comp_final = _value_lookup(uniq3, root3, open_comp)
@@ -417,4 +440,6 @@ def phase1_local(
         log_mask=q_m,
         n_components=n_comp.astype(I32),
         flags=flags,
+        hook_rounds=hook_ran + hook3_ran,
+        splice_rounds=splice_ran,
     )

@@ -77,19 +77,20 @@ def circuit_from_mate_jnp(mate: jnp.ndarray,
     n_stubs = mate.shape[0]
     iota = jnp.arange(n_stubs, dtype=mate.dtype)
     valid = mate >= 0
-    nxt = jnp.where(valid, mate ^ 1, iota)
-
-    t = mate[start_stub ^ 1]
-    ptr = nxt.at[t].set(t).astype(I32)
-    dist = jnp.ones(n_stubs, dtype=jnp.int32).at[t].set(0)
-    reach = jnp.zeros(n_stubs, dtype=I32).at[t].set(1)
-    rounds = int(np.ceil(np.log2(max(2, n_stubs)))) + 1
-    _, dist, reach = jax.lax.fori_loop(
-        0, rounds, lambda _, c: _kref.pointer_double_rank_ref(*c),
-        (ptr, dist, reach))
+    with jax.named_scope("rank"):
+        nxt = jnp.where(valid, mate ^ 1, iota)
+        t = mate[start_stub ^ 1]
+        ptr = nxt.at[t].set(t).astype(I32)
+        dist = jnp.ones(n_stubs, dtype=jnp.int32).at[t].set(0)
+        reach = jnp.zeros(n_stubs, dtype=I32).at[t].set(1)
+        rounds = int(np.ceil(np.log2(max(2, n_stubs)))) + 1
+        _, dist, reach = jax.lax.fori_loop(
+            0, rounds, lambda _, c: _kref.pointer_double_rank_ref(*c),
+            (ptr, dist, reach))
     return emit_circuit(valid, dist, reach)
 
 
+@jax.named_scope("emit")
 def emit_circuit(valid: jnp.ndarray, dist: jnp.ndarray,
                  reach: jnp.ndarray) -> jnp.ndarray:
     """Rank → walk-order emission shared by every Phase 3 backend.
@@ -197,6 +198,7 @@ def splice_components_np(
 # device Phase 3 (jittable; runs inside the fused engine program)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("cc")
 def _cc_cycle_labels(mate: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
     """Component labels (min member stub id) of the sibling∘mate cycle
     structure, by pointer-doubling min-label propagation.
@@ -221,7 +223,7 @@ def splice_components_jnp(
     stub_vertex: jnp.ndarray,
     valid: jnp.ndarray,
     rounds: int = 64,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Jittable twin of :func:`splice_components_np` for perfect matchings.
 
     Merges the remaining edge-disjoint cycles that cross at shared (pivot)
@@ -235,8 +237,9 @@ def splice_components_jnp(
 
     Requires every valid stub to be mated (true after all merge levels;
     the engine asserts it).  Invalid slots (padding) are ignored.  Returns
-    ``(mate', converged)``; non-convergence within ``rounds`` only happens
-    on disconnected inputs, which downstream validation rejects anyway.
+    ``(mate', converged, rounds_run)``; non-convergence within ``rounds``
+    only happens on disconnected inputs, which downstream validation
+    rejects anyway.
     """
     n = mate.shape[0]
     iota = jnp.arange(n, dtype=I32)
@@ -301,8 +304,10 @@ def splice_components_jnp(
         return state[2] & (state[3] > 0)
 
     init = (mate, lab0, jnp.array(True), jnp.array(rounds, I32))
-    mate, _, still_changing, _ = jax.lax.while_loop(cond, round_fn, init)
-    return mate, ~still_changing
+    with jax.named_scope("splice"):
+        mate, _, still_changing, left = jax.lax.while_loop(
+            cond, round_fn, init)
+    return mate, ~still_changing, rounds - left
 
 
 def phase3_device(mate: jnp.ndarray, stub_vertex: jnp.ndarray,
@@ -317,14 +322,14 @@ def phase3_device(mate: jnp.ndarray, stub_vertex: jnp.ndarray,
     The batched fused program wraps this whole function in ``jax.vmap``
     (one call per graph in the batch, DESIGN.md §8).
 
-    Returns ``(circuit [E], mate', splice_converged)``.
+    Returns ``(circuit [E], mate', splice_converged, splice_rounds_run)``.
     """
     valid = mate >= 0
-    mate2, ok = splice_components_jnp(mate, stub_vertex, valid,
-                                      rounds=splice_rounds)
+    mate2, ok, ran = splice_components_jnp(mate, stub_vertex, valid,
+                                           rounds=splice_rounds)
     start = jnp.argmax(valid).astype(I32)
     circuit = circuit_from_mate_jnp(mate2, start)
-    return circuit, mate2, ok
+    return circuit, mate2, ok, ran
 
 
 # ---------------------------------------------------------------------------
@@ -405,6 +410,7 @@ def _ring_perm(n: int):
     return [(i, (i + 1) % n) for i in range(n)]
 
 
+@jax.named_scope("cc")
 def _cc_labels_sharded(mate_sh: jnp.ndarray, axes, n: int) -> jnp.ndarray:
     """Sharded twin of :func:`_cc_cycle_labels`: min-label propagation by
     pointer doubling where each round resolves remote pointers with one
@@ -445,7 +451,7 @@ def splice_components_sharded(
     n: int,
     p3v_cap: int,
     rounds: int = 64,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Sharded twin of :func:`splice_components_jnp`.
 
     Per round: canonical (stub, vertex, comp, mate) records ring-ship to
@@ -453,9 +459,11 @@ def splice_components_sharded(
     table, where the oracle's per-vertex rep/vote/rotate logic runs
     verbatim on the locally-sorted records; mate rotations and component
     relabels ring back to the stub/label owners.  Returns
-    ``(mate_sh', ok)`` — ``ok`` is convergence AND no vertex-table
-    overflow (``p3v_cap`` is sized from the degree profile, so overflow
-    only means undersized caps, never silent corruption).
+    ``(mate_sh', ok, rounds_run)`` — ``ok`` is convergence AND no
+    vertex-table overflow (``p3v_cap`` is sized from the degree profile,
+    so overflow only means undersized caps, never silent corruption);
+    the round count is the same on every device (``changed`` is a
+    ``psum``).
     """
     S = mate_sh.shape[0]
     me = jax.lax.axis_index(axes).astype(I32)
@@ -615,11 +623,13 @@ def splice_components_sharded(
 
     init = (mate_sh, lab0, jnp.array(True), jnp.array(rounds, I32),
             jnp.array(False))
-    mate_sh, _, still_changing, _, of = jax.lax.while_loop(
-        cond, round_fn, init)
-    return mate_sh, ~still_changing & ~of
+    with jax.named_scope("splice"):
+        mate_sh, _, still_changing, left, of = jax.lax.while_loop(
+            cond, round_fn, init)
+    return mate_sh, ~still_changing & ~of, rounds - left
 
 
+@jax.named_scope("rank")
 def _rank_sharded(mate_sh: jnp.ndarray, axes,
                   n: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Sharded list ranking: the doubling loop of
@@ -681,19 +691,21 @@ def phase3_sharded(mate_sh: jnp.ndarray, sv_sh: jnp.ndarray, axes, n: int,
     With ``gather_circuit=True`` (the default) the run's ONE
     ``all_gather`` happens here — at the very end, on the post-rank
     (mate, dist, reach) triple — and the function returns the replicated
-    ``(circuit [E], mate [2E], ok)`` exactly like :func:`phase3_device`.
-    With ``gather_circuit=False`` nothing is gathered: the triple comes
-    back still sharded (``(mate_sh, dist_sh, reach_sh, ok)``) and the
-    caller (the engine's :class:`PendingRun`) emits the circuit host-side
-    from the fetched shards via the same :func:`emit_circuit` ordering.
+    ``(circuit [E], mate [2E], ok, rounds)`` exactly like
+    :func:`phase3_device`.  With ``gather_circuit=False`` nothing is
+    gathered: the triple comes back still sharded (``(mate_sh, dist_sh,
+    reach_sh, ok, rounds)``) and the caller (the engine's
+    :class:`PendingRun`) emits the circuit host-side from the fetched
+    shards via the same :func:`emit_circuit` ordering.
     """
-    mate2_sh, ok = splice_components_sharded(
+    mate2_sh, ok, ran = splice_components_sharded(
         mate_sh, sv_sh, axes, n, p3v_cap, rounds=splice_rounds)
     dist_sh, reach_sh = _rank_sharded(mate2_sh, axes, n)
     if not gather_circuit:
-        return mate2_sh, dist_sh, reach_sh, ok
-    packed = jnp.stack([mate2_sh, dist_sh, reach_sh], axis=1)   # [S, 3]
-    g = jax.lax.all_gather(packed, axes, tiled=True)            # [n·S, 3]
+        return mate2_sh, dist_sh, reach_sh, ok, ran
+    with jax.named_scope("emit"):
+        packed = jnp.stack([mate2_sh, dist_sh, reach_sh], axis=1)  # [S, 3]
+        g = jax.lax.all_gather(packed, axes, tiled=True)           # [n·S, 3]
     mate2 = g[:n_stubs, 0]
     circuit = emit_circuit(mate2 >= 0, g[:n_stubs, 1], g[:n_stubs, 2])
-    return circuit, mate2, ok
+    return circuit, mate2, ok, ran

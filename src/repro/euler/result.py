@@ -72,6 +72,7 @@ class EulerResult:
     graph: Optional[Graph] = None    # the (unpadded) input graph
     padded_edges: int = 0            # dummy edges added for shape bucketing
     phase3_converged: bool = True
+    phase3_rounds: Optional[int] = None  # device: pivot-splice rounds run
     timings: Dict[str, float] = dataclasses.field(default_factory=dict)
     cache: CacheStats = dataclasses.field(default_factory=CacheStats)
     valid: Optional[bool] = None     # set by validate(); None = unchecked
@@ -109,7 +110,9 @@ class EulerResult:
         """Normalize the device engine's per-level ``[n, 4]`` Int64-count
         arrays (``[2·parked, 3·opens, 4·touch, 4·components]`` per
         partition) into the same :class:`LevelStats` the host engine
-        reports, so both backends expose one metrics shape."""
+        reports, so both backends expose one metrics shape.  A fifth and
+        sixth column, where present, are the Phase 1 hook and splice
+        rounds the partition ran (``[n, 6]``, the engine's layout)."""
         out: List[LevelStats] = []
         for lvl, m in enumerate(metrics_per_level):
             m = np.asarray(m)
@@ -121,6 +124,8 @@ class EulerResult:
                     open_stubs=int(row[1]) // 3,
                     touch=int(row[2]) // 4,
                     components=int(row[3]) // 4,
+                    hook_rounds=int(row[4]) if len(row) > 4 else None,
+                    splice_rounds=int(row[5]) if len(row) > 5 else None,
                 )
                 for pid, row in enumerate(m)
             ]
@@ -130,8 +135,9 @@ class EulerResult:
 
     def metrics_arrays(self) -> List[np.ndarray]:
         """Back-compat raw view: per-level ``[n, 4]`` Int64-count arrays
-        (inverse of :meth:`levels_from_metrics`; device-backend levels
-        only — host levels additionally carry boundary counts)."""
+        (inverse of :meth:`levels_from_metrics` on the Int64 counts; the
+        loop rounds stay on the levels; device-backend levels only — host
+        levels additionally carry boundary counts)."""
         return [
             np.array(
                 [[2 * s.remote_copies, 3 * s.open_stubs, 4 * s.touch,

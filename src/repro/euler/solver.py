@@ -77,11 +77,16 @@ class PendingSolve:
     :meth:`EulerSolver.solve_batch` path returns.  The serving pipeline
     holds one of these per in-flight flush so host prep of the next
     flush overlaps device execution of this one (DESIGN.md §9).
+
+    It also holds the solve's open root span (``solve`` or
+    ``solve_batch``, DESIGN.md §13): ``results()`` records its ``wait``
+    and ``strip`` children, sets the device loop counters on it, and
+    closes it.
     """
 
     def __init__(self, solver: "EulerSolver", run: PendingRun,
                  graphs: List[Graph], key: BucketKey, hit: bool,
-                 t0: float, t_prep: float, batch: int):
+                 t0: float, t_prep: float, batch: int, root: obs.Span):
         self._solver = solver
         self._run = run
         self._graphs = graphs
@@ -90,6 +95,7 @@ class PendingSolve:
         self._t0 = t0
         self._t_prep = t_prep
         self._batch = batch          # reported width (1 = single program)
+        self._root = root
         self._out: Optional[List[EulerResult]] = None
 
     @property
@@ -107,19 +113,24 @@ class PendingSolve:
         """Block for the device run; one result per graph, input order."""
         if self._out is not None:
             return self._out
-        with self._solver.trace.span("fetch", bucket=self._key[0],
-                                     width=self._batch):
-            results = self._run.wait()
-        total_s = time.perf_counter() - self._t0
-        for g, res in zip(self._graphs, results):
-            res.graph = g
-            res.padded_edges = self._key[0] - g.num_edges
-            res.circuit = strip_circuit(res.circuit, g.num_edges)
-            res.cache = dataclasses.replace(
-                self._solver.cache_stats, bucket=self._key,
-                hit=self._hit, batch=self._batch)
-            res.timings["prepare_s"] = self._t_prep
-            res.timings["total_s"] = total_s
+        with self._root.scope():
+            self._run.sync()
+            with self._solver.trace.span("strip"):
+                results = self._run.wait()
+                total_s = time.perf_counter() - self._t0
+                for g, res in zip(self._graphs, results):
+                    res.graph = g
+                    res.padded_edges = self._key[0] - g.num_edges
+                    res.circuit = strip_circuit(res.circuit, g.num_edges)
+                    res.cache = dataclasses.replace(
+                        self._solver.cache_stats, bucket=self._key,
+                        hit=self._hit, batch=self._batch)
+                    res.timings["prepare_s"] = self._t_prep
+                    res.timings["total_s"] = total_s
+        counters = [_loop_counters(res) for res in results]
+        self._root.set(**(counters[0] if len(counters) == 1 else
+                          {k: [c[k] for c in counters] for k in counters[0]}))
+        self._root.end()
         self._out = results
         return results
 
@@ -128,6 +139,15 @@ class PendingSolve:
         if len(self._graphs) != 1:
             raise ValueError("batched solve: use results()")
         return self.results()[0]
+
+
+def _loop_counters(res: EulerResult) -> dict:
+    """A fused solve's device loop counters, as its root span records
+    them: per-level lists of per-partition Phase 1 rounds, and Phase 3's
+    splice rounds."""
+    return {"hook_rounds": [ls.hook_rounds for ls in res.levels],
+            "splice_rounds": [ls.splice_rounds for ls in res.levels],
+            "phase3_rounds": res.phase3_rounds}
 
 
 class EulerSolver:
@@ -424,7 +444,8 @@ class EulerSolver:
                 hit = self._prep_cache.get(id(graph))
                 if hit is not None and hit[0] is graph:
                     return hit[1]
-            part = self._partition(graph, part_of_vertex)
+            with self.trace.span("partition", parts=self.n_parts):
+                part = self._partition(graph, part_of_vertex)
             e_cap = ceil_pow2(graph.num_edges, self.min_bucket_edges)
             g_pad, part_pad = pad_graph(graph, part, e_cap)
             pg = partition_graph(g_pad, part_pad)
@@ -774,8 +795,8 @@ class EulerSolver:
             return self.solve_async(graph, part_of_vertex).result()
 
         # ---- eager per-level oracle (synchronous by design) ----
-        pg, tree, key = self._prepare(graph, part_of_vertex)
-        t_prep = time.perf_counter() - t0
+        with self.trace.span("prepare") as prep:
+            pg, tree, key = self._prepare(graph, part_of_vertex)
         eng = self._engine_for(key)
         hit = self._account(key, None)
         with self.trace.span("solve_eager", bucket=key[0], hit=hit):
@@ -785,7 +806,7 @@ class EulerSolver:
         res.circuit = strip_circuit(res.circuit, graph.num_edges)
         res.cache = dataclasses.replace(self.cache_stats, bucket=key,
                                         hit=hit, batch=1)
-        res.timings["prepare_s"] = t_prep
+        res.timings["prepare_s"] = prep.dur_s
         res.timings["total_s"] = time.perf_counter() - t0
         return res
 
@@ -796,26 +817,36 @@ class EulerSolver:
         :class:`PendingSolve` whose ``result()`` performs the run's one
         host sync.  Device backend + fused mode only (jax dispatches the
         compiled program asynchronously, so host code — prep of the next
-        request, batching decisions — overlaps device execution)."""
+        request, batching decisions — overlaps device execution).
+
+        The solve is one span tree (DESIGN.md §13): a root ``solve`` span
+        with a fresh ``req`` id, and beneath it ``prepare`` (holding
+        ``partition``), ``stage`` (holding ``upload``), ``launch``, then
+        at ``result()`` ``wait`` and ``strip``."""
         if self.backend != "device":
             raise ValueError("solve_async is a device-backend path; the "
                              "host engine runs synchronously via solve()")
         t0 = time.perf_counter()
-        with self._lock:
-            pg, tree, key = self._prepare(graph, part_of_vertex)
-            t_prep = time.perf_counter() - t0
-            eng = self._engine_for(key)
-            hit = self._account(key, None)
-            staged = eng._stage(pg, resident=self.device_resident)
-        # program call OUTSIDE the session lock: a cold program compiles
-        # here, so background prewarm compiles (the compile service) never
-        # block a concurrent serving dispatch (DESIGN.md §12).  A miss's
-        # launch time ≈ compile time (the span feeds euler_compile_seconds).
-        with self.trace.span("launch",
-                             metric=None if hit else self._h_compile,
-                             bucket=key[0], width=1, hit=hit):
-            run = eng._launch(staged, t0)
-        return PendingSolve(self, run, [graph], key, hit, t0, t_prep, 1)
+        root = self.trace.request("solve", width=1).start()
+        with root.scope():
+            with self._lock:
+                with self.trace.span("prepare") as prep:
+                    pg, tree, key = self._prepare(graph, part_of_vertex)
+                eng = self._engine_for(key)
+                hit = self._account(key, None)
+                staged = eng._stage(pg, resident=self.device_resident)
+            # program call OUTSIDE the session lock: a cold program
+            # compiles here, so background prewarm compiles (the compile
+            # service) never block a concurrent serving dispatch (DESIGN.md
+            # §12).  A miss's launch time ≈ compile time (the span feeds
+            # euler_compile_seconds).
+            with self.trace.span("launch",
+                                 metric=None if hit else self._h_compile,
+                                 bucket=key[0], width=1, hit=hit):
+                run = eng._launch(staged, t0)
+        root.set(bucket=key[0], hit=hit)
+        return PendingSolve(self, run, [graph], key, hit, t0, prep.dur_s,
+                            1, root)
 
     def solve_batch(self, graphs: Iterable[Graph],
                     fused: Optional[bool] = None) -> List[EulerResult]:
@@ -864,27 +895,31 @@ class EulerSolver:
         if len(graphs) == 1:
             return self.solve_async(graphs[0])
         t0 = time.perf_counter()
-        with self._lock:
-            preps = [self._prepare(g, None) for g in graphs]
-            keys = {p[2] for p in preps}
-            if len(keys) > 1:
-                raise ValueError(
-                    f"solve_batch needs same-bucket graphs, got {len(keys)} "
-                    f"distinct buckets; group with bucket_of() or use "
-                    f"solve_many(batch=...)"
-                )
-            key = preps[0][2]
-            t_prep = time.perf_counter() - t0
-            B = len(graphs)
-            eng = self._engine_for(key)
-            hit = self._account(key, B)
-            staged = eng._stage_batch([p[0] for p in preps])
-        # see solve_async: compile/dispatch happens outside the lock
-        with self.trace.span("launch",
-                             metric=None if hit else self._h_compile,
-                             bucket=key[0], width=B, hit=hit):
-            run = eng._launch(staged, t0)
-        return PendingSolve(self, run, graphs, key, hit, t0, t_prep, B)
+        B = len(graphs)
+        root = self.trace.request("solve_batch", width=B).start()
+        with root.scope():
+            with self._lock:
+                with self.trace.span("prepare", width=B) as prep:
+                    preps = [self._prepare(g, None) for g in graphs]
+                keys = {p[2] for p in preps}
+                if len(keys) > 1:
+                    raise ValueError(
+                        f"solve_batch needs same-bucket graphs, got "
+                        f"{len(keys)} distinct buckets; group with "
+                        f"bucket_of() or use solve_many(batch=...)"
+                    )
+                key = preps[0][2]
+                eng = self._engine_for(key)
+                hit = self._account(key, B)
+                staged = eng._stage_batch([p[0] for p in preps])
+            # see solve_async: compile/dispatch happens outside the lock
+            with self.trace.span("launch",
+                                 metric=None if hit else self._h_compile,
+                                 bucket=key[0], width=B, hit=hit):
+                run = eng._launch(staged, t0)
+        root.set(bucket=key[0], hit=hit)
+        return PendingSolve(self, run, graphs, key, hit, t0, prep.dur_s,
+                            B, root)
 
     def solve_many(self, graphs: Iterable[Graph],
                    fused: Optional[bool] = None,

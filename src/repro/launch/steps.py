@@ -449,7 +449,7 @@ def build_euler_cell(arch: ArchConfig, cell: ShapeCell, mesh) -> Cell:
         out_specs = FusedOut(
             circuit=P(None), mate=P(None),
             flags=P(axes, None, None), metrics=P(axes, None, None),
-            phase3_ok=P(),
+            phase3_ok=P(), phase3_rounds=P(),
         )
         p3 = float(2 * E * np.log2(max(2, 2 * E)) * 6)  # splice + list-rank
         return Cell(

@@ -18,12 +18,11 @@ jax, like ``repro.analysis.lint``.
 from .export import MetricsServer, render_prometheus, snapshot
 from .metrics import (Counter, Family, Gauge, Histogram, Registry,
                       default_registry)
-from .trace import (NULL_SPAN, NullTraceLog, Span, TraceLog,
-                    default_tracelog)
+from .trace import NullTraceLog, Span, TraceLog, default_tracelog
 
 __all__ = [
     "Counter", "Family", "Gauge", "Histogram", "Registry",
     "default_registry",
-    "Span", "TraceLog", "NullTraceLog", "NULL_SPAN", "default_tracelog",
+    "Span", "TraceLog", "NullTraceLog", "default_tracelog",
     "MetricsServer", "render_prometheus", "snapshot",
 ]

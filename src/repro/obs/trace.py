@@ -20,69 +20,116 @@ both the trace tree and the latency distribution.
 ...         t[0] = 3.0
 >>> [(s["name"], s["dur_s"], s["parent"]) for s in log.spans()]
 [('launch', 2.0, 1), ('flush', 3.0, None)]
+
+A request's spans form one tree: ``log.request(name)`` opens a span
+that draws a fresh request id (``req``), which every span opened
+beneath it inherits.  A request may outlive the block that opened it
+(an asynchronous solve is dispatched in one call and fetched in
+another): ``start()`` opens it without making it the thread's parent,
+``scope()`` makes it the parent for one block, ``end()`` closes it.
+
+>>> root = log.request("solve").start()
+>>> with root.scope():
+...     with log.span("prepare"):
+...         t[0] = 4.0
+>>> root.end()
+>>> [(s["name"], s["req"], s["parent"] == root.id) for s in log.spans()[2:]]
+[('prepare', 1, True), ('solve', 1, False)]
+
+The log's default clock is ``time.perf_counter``.  While a span is open
+it is also a ``jax.profiler.TraceAnnotation`` named ``repro.<name>``
+when jax is loaded, so a profile shows the program's spans on the host
+plane beside the device ops; without a profiler session that is a no-op,
+and without jax nothing is done (this module never imports jax).
 """
 from __future__ import annotations
 
+import contextlib
 import json
+import sys
 import threading
 import time
 from collections import deque
-from typing import IO, Dict, List, Optional, Union
+from typing import IO, Dict, Iterator, List, Optional, Union
+
+
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` for ``name`` while a
+    profiler session runs, else None.  Never imports jax."""
+    jax = sys.modules.get("jax")
+    prof = getattr(jax, "profiler", None)
+    if prof is None or not prof.TraceAnnotation.is_enabled():
+        return None
+    ann = prof.TraceAnnotation("repro." + name)
+    ann.__enter__()
+    return ann
 
 
 class Span:
     """One timed section.  Use as a context manager; attributes passed
     at creation plus any added via ``set(...)`` land in the record."""
 
-    __slots__ = ("log", "name", "attrs", "id", "parent", "t0", "dur_s",
-                 "status", "_metric")
+    __slots__ = ("log", "name", "attrs", "id", "parent", "req", "t0",
+                 "dur_s", "status", "_metric", "_new_req", "_ann")
 
     def __init__(self, log: "TraceLog", name: str, metric=None,
-                 **attrs):
+                 new_req: bool = False, **attrs):
         self.log = log
         self.name = name
         self.attrs: Dict[str, object] = dict(attrs)
         self.id: Optional[int] = None
         self.parent: Optional[int] = None
+        self.req: Optional[int] = None
         self.t0 = 0.0
         self.dur_s = 0.0
         self.status = "ok"
         self._metric = metric
+        self._new_req = new_req
+        self._ann = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
         return self
 
-    def __enter__(self) -> "Span":
+    def start(self) -> "Span":
+        """Open the span without making it this thread's parent."""
         self.log._open(self)
         return self
 
-    def __exit__(self, exc_type, exc, tb) -> bool:
+    def end(self, exc_type=None) -> None:
+        """Close the span (``exc_type``: the exception that ended it)."""
         if exc_type is not None:
             self.status = "error"
             self.attrs.setdefault("error", exc_type.__name__)
         self.log._close(self)
         if self._metric is not None:
             self._metric.observe(self.dur_s)
-        return False
 
+    @contextlib.contextmanager
+    def scope(self) -> Iterator["Span"]:
+        """Make this open span the parent of spans opened in the block;
+        an exception escaping the block ends the span as an error."""
+        st = self.log._stack()
+        st.append(self)
+        try:
+            yield self
+        except BaseException as e:
+            st.pop()
+            self.end(type(e))
+            raise
+        st.pop()
 
-class _NullSpan:
-    """No-op stand-in so call sites never branch on 'tracing enabled'."""
-
-    __slots__ = ()
-
-    def set(self, **attrs) -> "_NullSpan":
-        return self
-
-    def __enter__(self) -> "_NullSpan":
+    def __enter__(self) -> "Span":
+        self.start()
+        self.log._stack().append(self)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        st = self.log._stack()
+        if st and st[-1] is self:
+            st.pop()
+        self.end(exc_type)
         return False
-
-
-NULL_SPAN = _NullSpan()
 
 
 class TraceLog:
@@ -94,11 +141,12 @@ class TraceLog:
     parentage never crosses threads.
     """
 
-    def __init__(self, capacity: int = 2048, clock=time.monotonic,
+    def __init__(self, capacity: int = 2048, clock=time.perf_counter,
                  sink: Union[None, str, IO[str]] = None):
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=capacity)
         self._next_id = 1
+        self._next_req = 1
         self._tls = threading.local()
         self.clock = clock
         self._sink: Optional[IO[str]] = None
@@ -113,6 +161,11 @@ class TraceLog:
     def span(self, name: str, metric=None, **attrs) -> Span:
         return Span(self, name, metric=metric, **attrs)
 
+    def request(self, name: str, **attrs) -> Span:
+        """A span that draws a fresh request id (``req``) when it opens;
+        every span opened beneath it carries the same ``req``."""
+        return Span(self, name, new_req=True, **attrs)
+
     def event(self, name: str, **attrs) -> None:
         """Record an instantaneous (zero-duration) span — for point
         occurrences like a jit retrace, where the surrounding timing
@@ -120,7 +173,7 @@ class TraceLog:
         with self.span(name, **attrs):
             pass
 
-    def _stack(self) -> List[int]:
+    def _stack(self) -> List[Span]:
         st = getattr(self._tls, "stack", None)
         if st is None:
             st = self._tls.stack = []
@@ -128,21 +181,29 @@ class TraceLog:
 
     def _open(self, span: Span) -> None:
         st = self._stack()
+        top = st[-1] if st else None
         with self._lock:
             span.id = self._next_id
             self._next_id += 1
-        span.parent = st[-1] if st else None
-        st.append(span.id)
+            if span._new_req:
+                span.req = self._next_req
+                self._next_req += 1
+        span.parent = top.id if top is not None else None
+        if not span._new_req and top is not None:
+            span.req = top.req
+        span._ann = _annotation(span.name)
         span.t0 = self.clock()
 
     def _close(self, span: Span) -> None:
         span.dur_s = self.clock() - span.t0
-        st = self._stack()
-        if st and st[-1] == span.id:
-            st.pop()
+        if span._ann is not None:
+            span._ann.__exit__(None, None, None)
+            span._ann = None
         rec = {"id": span.id, "parent": span.parent, "name": span.name,
                "t0": span.t0, "dur_s": span.dur_s, "status": span.status,
                "thread": threading.current_thread().name}
+        if span.req is not None:
+            rec["req"] = span.req
         if span.attrs:
             rec["attrs"] = dict(span.attrs)
         with self._lock:
@@ -173,15 +234,26 @@ class TraceLog:
 
 
 class NullTraceLog(TraceLog):
-    """Tracing disabled: ``span()`` returns a shared no-op span and
-    nothing is recorded.  Engine/solver default to the process trace
-    log; pass one of these to switch instrumentation off wholesale."""
+    """Tracing disabled: spans still time themselves (``dur_s``; the
+    solver's ``prepare_s`` is a span's duration) but get no id, feed no
+    histogram, annotate no profile and are never recorded.  Engine/solver
+    default to the process trace log; pass one of these to switch
+    instrumentation off wholesale."""
 
     def __init__(self):
         super().__init__(capacity=1)
 
-    def span(self, name: str, metric=None, **attrs) -> _NullSpan:  # type: ignore[override]
-        return NULL_SPAN
+    def span(self, name: str, metric=None, **attrs) -> Span:
+        return Span(self, name)
+
+    def request(self, name: str, **attrs) -> Span:
+        return Span(self, name)
+
+    def _open(self, span: Span) -> None:
+        span.t0 = self.clock()
+
+    def _close(self, span: Span) -> None:
+        span.dur_s = self.clock() - span.t0
 
 
 # Process-default trace log, mirroring metrics.DEFAULT.

@@ -6,7 +6,12 @@ No jax anywhere: the obs layer is stdlib-only by design (DESIGN.md §13)
 so instrumentation can never drag device initialization into a tool.
 """
 import json
+import os
+import subprocess
+import sys
 import threading
+import time
+import types
 import urllib.error
 import urllib.request
 
@@ -238,6 +243,129 @@ def test_null_tracelog_records_nothing():
         sp.set(more=2)      # no-op, chainable surface
     log.event("y")
     assert len(log) == 0 and log.spans() == []
+
+
+def test_null_tracelog_spans_still_time_themselves():
+    t = [0.0]
+    log = NullTraceLog()
+    log.clock = lambda: t[0]
+    with log.span("prepare") as sp:
+        t[0] = 2.5
+    assert sp.dur_s == 2.5 and sp.id is None
+    assert len(log) == 0
+
+
+def test_request_tree_outlives_its_block_and_shares_req():
+    """A root opened in one call (dispatch) and closed in another
+    (fetch): children opened under ``scope()`` nest in it, inherit its
+    ``req``; a second request draws a new one."""
+    t = [0.0]
+    log = TraceLog(clock=lambda: t[0])
+    with log.span("flush"):
+        root = log.request("solve", width=1).start()
+        with root.scope():
+            with log.span("prepare"):
+                with log.span("partition"):
+                    t[0] = 1.0
+        with log.span("outside"):
+            pass
+    with root.scope():
+        with log.span("wait"):
+            t[0] = 3.0
+    root.set(phase3_rounds=2)
+    root.end()
+    other = log.request("solve").start()
+    other.end()
+    by = {s["name"]: s for s in log.spans()}
+    first = [s for s in log.spans() if s["name"] == "solve"][0]
+    assert first["parent"] == by["flush"]["id"]
+    assert first["dur_s"] == 3.0 and first["attrs"]["phase3_rounds"] == 2
+    for name in ("prepare", "wait"):
+        assert by[name]["parent"] == first["id"]
+        assert by[name]["req"] == first["req"]
+    assert by["partition"]["req"] == first["req"]
+    assert "req" not in by["outside"] and "req" not in by["flush"]
+    assert by["outside"]["parent"] == by["flush"]["id"]
+    assert by["solve"]["req"] == first["req"] + 1    # the second request
+
+
+def test_scope_ends_the_span_on_error():
+    log = TraceLog(clock=lambda: 0.0)
+    root = log.request("solve").start()
+    with pytest.raises(KeyError):
+        with root.scope():
+            raise KeyError("x")
+    (s,) = log.spans()
+    assert s["status"] == "error" and s["attrs"]["error"] == "KeyError"
+    with log.span("after"):      # the stack was popped
+        pass
+    assert log.spans()[-1]["parent"] is None
+
+
+def test_default_clock_is_perf_counter():
+    assert TraceLog().clock is time.perf_counter
+    assert default_tracelog().clock is time.perf_counter
+
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``."""
+
+    enabled = True
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.enabled
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+def test_spans_annotate_a_profile_when_jax_is_loaded(monkeypatch):
+    fake = types.SimpleNamespace(
+        profiler=types.SimpleNamespace(TraceAnnotation=_FakeAnnotation))
+    monkeypatch.setitem(sys.modules, "jax", fake)
+    _FakeAnnotation.log = []
+    log = TraceLog(clock=lambda: 0.0)
+    with log.span("solve"):
+        log.event("retrace")
+    assert _FakeAnnotation.log == [
+        ("enter", "repro.solve"), ("enter", "repro.retrace"),
+        ("exit", "repro.retrace"), ("exit", "repro.solve")]
+    # no profiler session: nothing is entered
+    _FakeAnnotation.enabled, _FakeAnnotation.log = False, []
+    with log.span("solve"):
+        pass
+    assert _FakeAnnotation.log == []
+    _FakeAnnotation.enabled = True
+    # tracing off: nothing is annotated
+    with NullTraceLog().span("solve"):
+        pass
+    assert _FakeAnnotation.log == []
+    # jax not loaded: nothing to annotate with
+    monkeypatch.delitem(sys.modules, "jax")
+    with log.span("solve"):
+        pass
+    assert _FakeAnnotation.log == []
+
+
+def test_obs_imports_without_jax():
+    env = dict(os.environ)
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env["PYTHONPATH"] = src
+    code = ("import sys, repro.obs as o; log = o.TraceLog();\n"
+            "with log.span('x'): pass\n"
+            "assert 'jax' not in sys.modules, sorted(sys.modules)")
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
 
 
 def test_process_defaults_are_singletons():

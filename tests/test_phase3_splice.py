@@ -66,11 +66,12 @@ def check_both_splices(g, mate):
     c_np = circuit_from_mate_np(m_np)
     validate_circuit(g, c_np)
     # device path
-    m_j, ok = jax.jit(splice_components_jnp)(
+    m_j, ok, rounds = jax.jit(splice_components_jnp)(
         jnp.asarray(mate, jnp.int32), jnp.asarray(sv, jnp.int32),
         jnp.asarray(mate >= 0),
     )
     assert bool(ok), "device splice did not converge"
+    assert 1 <= int(rounds) <= 64
     m_j = np.asarray(m_j, dtype=np.int64)
     # still a perfect matching over the same stubs
     assert (m_j >= 0).all()
@@ -141,10 +142,11 @@ def test_phase3_device_end_to_end():
     """phase3_device = splice + list-rank in one jitted program."""
     g, mate = graph_of_cycles(7, [[0, 1, 2], [0, 3, 4], [0, 5, 6]])
     sv = stub_vertices(g)
-    circ, m2, ok = jax.jit(phase3_device)(
+    circ, m2, ok, rounds = jax.jit(phase3_device)(
         jnp.asarray(mate, jnp.int32), jnp.asarray(sv, jnp.int32)
     )
     assert bool(ok)
+    assert int(rounds) >= 2      # three cycles at one pivot: a rotation
     circ = np.asarray(circ, dtype=np.int64)
     assert (circ >= 0).all()
     validate_circuit(g, circ)

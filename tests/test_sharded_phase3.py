@@ -112,7 +112,7 @@ def random_cycle_cover(n_vertices, n_trails, trail_len):
 
 def check(mate, sv, E, n, label):
     n_stubs = 2 * E
-    c_rep, m_rep, ok_rep = jax.jit(phase3_device)(
+    c_rep, m_rep, ok_rep, r_rep = jax.jit(phase3_device)(
         jnp.asarray(mate), jnp.asarray(sv))
     assert bool(ok_rep), f"{label}: replicated did not converge"
 
@@ -130,9 +130,10 @@ def check(mate, sv, E, n, label):
 
     with mesh:
         fn = jax.jit(shard_map(f, mesh, (P("x"), P("x")),
-                               (P(None), P(None), P())))
-        c_sh, m_sh, ok_sh = fn(jnp.asarray(mate_p), jnp.asarray(sv_p))
+                               (P(None), P(None), P(), P())))
+        c_sh, m_sh, ok_sh, r_sh = fn(jnp.asarray(mate_p), jnp.asarray(sv_p))
     assert bool(ok_sh), f"{label}: sharded did not converge"
+    assert int(r_sh) == int(r_rep), f"{label}: splice rounds differ"
     assert np.array_equal(np.asarray(m_rep), np.asarray(m_sh)), (
         f"{label}: mate mismatch")
     assert np.array_equal(np.asarray(c_rep), np.asarray(c_sh)), (
