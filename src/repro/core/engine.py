@@ -86,13 +86,16 @@ class EngineCaps:
     p3v_cap: int = 0          # sharded Phase 3 per-device vertex-record
                               # table width (0 → e_cap, the safe bound)
 
-    def phase1(self) -> Phase1Caps:
+    def phase1(self, stub_space: int = 0) -> Phase1Caps:
+        """Phase 1's caps; ``stub_space`` (2·num_edges, known where the
+        program is built for one edge count) bounds every id it sees."""
         return Phase1Caps(
             open_cap=self.open_cap,
             touch_cap=self.touch_cap,
             hook_rounds=self.hook_rounds,
             splice_rounds=self.splice_rounds,
             static_splice=self.static_splice,
+            stub_space=stub_space,
         )
 
     def pair_cap(self) -> int:
@@ -648,15 +651,17 @@ class DistributedEngine:
     # ------------------------------------------------------------------
     # the superstep program
     # ------------------------------------------------------------------
-    def _make_superstep_core(self):
+    def _make_superstep_core(self, stub_space: int = 0):
         """The per-device superstep body (unsharded view): ship + Phase 1
         + table refresh.  Shared verbatim by the eager per-level program
-        and the fused level scan, so both execute identical supersteps."""
+        and the fused level scan, so both execute identical supersteps;
+        the fused scan, built for one edge count, also passes its stub
+        space, which lets Phase 1 look ids up in tables (DESIGN.md §2)."""
         n, c = self.n, self.caps
         axes = self.axes
         osc = c.open_ship_cap or c.open_cap
         tsc = c.touch_ship_cap or c.touch_cap
-        p1caps = c.phase1()
+        p1caps = c.phase1(stub_space)
         deferred = self.deferred_transfer
 
         def core(lvl, anc, state: EngineState):
@@ -906,7 +911,7 @@ class DistributedEngine:
         gather = self.gather_circuit
         p3v = c.p3v_cap or num_edges           # vertex-record table width
         wcap = c.mate_ship_cap or 2 * c.pair_cap()
-        core = self._make_superstep_core()
+        core = self._make_superstep_core(stub_space=n_stubs)
 
         def one_graph(anc, state: EngineState, sv):
             """Whole-run body for ONE graph on one device (unsharded

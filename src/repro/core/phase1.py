@@ -20,7 +20,11 @@ bounded round counts with convergence flags (asserted in tests and checked
 at runtime by the engine).
 
 Component ids are *min member stub id* — globally unique and stable across
-levels and devices, so pathMaps merge without coordination.
+levels and devices, so pathMaps merge without coordination.  Every id
+Phase 1 looks up (a stub or a component) therefore lies in the stub space
+``[0, 2·num_edges)``; where the caller gives that bound, the CC runs over
+the ids themselves and each exact-match lookup is one gather from a table
+indexed by the id (DESIGN.md §2).
 """
 from __future__ import annotations
 
@@ -46,6 +50,9 @@ class Phase1Caps:
     static_splice: bool = False  # unroll splice and hook rounds (roofline
                                  # analysis: while-loop bodies are
                                  # cost-counted once)
+    stub_space: int = 0     # every stub/comp id is below this (2·num_edges):
+                            # lookups are tables over it; 0 → unknown, binary
+                            # search.  Derived, not a setting
 
 
 class OpenTable(NamedTuple):
@@ -114,6 +121,45 @@ def hook_round_budget(caps: Phase1Caps, pool: int, touch_cap: int) -> int:
     return sum(_hook_budgets(caps, pool, touch_cap))
 
 
+def _in_space(values, space: int):
+    """Scatter indices: ids below ``space`` as they are, the rest (BIG
+    padding) at ``space``, which ``mode="drop"`` skips."""
+    return jnp.where(values < space, values, space)
+
+
+def _take(table, q, fill):
+    """``table[q]`` for ids inside the table, ``fill`` for the rest."""
+    inside = q < table.shape[0]
+    return jnp.where(inside, table[jnp.where(inside, q, 0)], fill)
+
+
+def member_table(values, space: int):
+    """``t[d]`` = 1 if id ``d`` is among ``values`` (BIG entries
+    ignored), else 0.  Int32: a TPU scatter into a narrower type
+    compiles to about 1.8 MB more program code."""
+    return jnp.zeros((space,), I32).at[_in_space(values, space)].set(
+        1, mode="drop")
+
+
+def relabel_table(mfrom, mto, space: int):
+    """``t[d] = mto[i]`` where ``mfrom[i] == d``, else ``d``.  The ids
+    of ``mfrom`` below BIG must be distinct."""
+    return jnp.arange(space, dtype=I32).at[_in_space(mfrom, space)].set(
+        mto, mode="drop")
+
+
+def _searcher(sorted_vals):
+    """``q ↦ (slot, found)`` by binary search on sorted ``sorted_vals``:
+    the first slot holding ``q`` (clipped insertion point when absent)."""
+    K = sorted_vals.shape[0]
+
+    def find(q):
+        j = jnp.clip(jnp.searchsorted(sorted_vals, q), 0, K - 1).astype(I32)
+        return j, sorted_vals[j] == q
+
+    return find
+
+
 def empty_open(cap: int) -> OpenTable:
     z = jnp.full((cap,), BIG, dtype=I32)
     return OpenTable(z, z, z, z, jnp.zeros((cap,), bool))
@@ -143,12 +189,24 @@ def _seg_starts(sorted_keys, idx_dtype=I32):
 
 
 def _cc_hook_jump(ca, cb, emask, universe, rounds: int,
-                  static: bool = False):
+                  static: bool = False, space: int = 0):
     """Min-label connected components over a value-keyed graph.
 
     Nodes are the values in ``universe`` ([K], BIG-padded); edges are
-    (ca[i], cb[i]) where ``emask[i]``.  Returns (sorted universe,
-    root *value* per universe slot, converged flag, rounds run).
+    (ca[i], cb[i]) where ``emask[i]``, each endpoint a universe value.
+    Returns ``(roots, seg, n_seg, converged, rounds run)``: ``roots``
+    maps values to their component's root *value* (identity for values
+    outside the universe, so BIG stays BIG); ``seg`` maps universe values
+    to distinct ids below ``n_seg`` (the splice vote's segments), and
+    everything else to ids the segment ops drop or that no vote reads.
+
+    With ``space`` (every value below it) the nodes are the ids below
+    ``space`` themselves, each its own first label, so the roots are
+    read straight from the labels; an id outside the universe is an
+    isolated node that keeps its label.  Without it the nodes are the
+    slots of the sorted universe, found by binary search.  Min-label
+    order is id order in both, so both give the same roots, flag and
+    round count.
 
     Runs at most ``rounds`` hook/jump/contract rounds and stops at the
     first round that leaves ``(lab, ea, eb)`` unchanged: a round is a
@@ -157,12 +215,17 @@ def _cc_hook_jump(ca, cb, emask, universe, rounds: int,
     ``rounds`` instead (roofline analysis: a ``while_loop`` body is
     cost-counted once).
     """
-    K = universe.shape[0]
-    uniq = bounded.sort(universe)
-    ia = jnp.clip(jnp.searchsorted(uniq, jnp.where(emask, ca, BIG)), 0, K - 1).astype(I32)
-    ib = jnp.clip(jnp.searchsorted(uniq, jnp.where(emask, cb, BIG)), 0, K - 1).astype(I32)
-    ia = jnp.where(emask, ia, K - 1)
-    ib = jnp.where(emask, ib, K - 1)
+    if space:
+        # a masked edge is a self-loop on id 0, whose label never moves
+        K = space
+        ia = jnp.where(emask, ca, 0)
+        ib = jnp.where(emask, cb, 0)
+    else:
+        K = universe.shape[0]
+        uniq = bounded.sort(universe)
+        find = _searcher(uniq)
+        ia = jnp.where(emask, find(ca)[0], K - 1)
+        ib = jnp.where(emask, find(cb)[0], K - 1)
     lab = jnp.arange(K, dtype=I32)
 
     def hook(lab, ea, eb):
@@ -197,13 +260,16 @@ def _cc_hook_jump(ca, cb, emask, universe, rounds: int,
             lambda st: st[3] & (st[4] < rounds), body,
             (lab, ea, eb, jnp.array(True), jnp.array(0, I32)))
     converged = jnp.all(hook(lab, ea, eb) == lab)
-    return uniq, uniq[lab], converged, ran
+    if space:
+        return (lambda v: _take(lab, v, v),
+                lambda v: _in_space(v, space), space, converged, ran)
+    root_val = uniq[lab]
 
+    def lookup(v):
+        j, found = find(v)      # a BIG slot is its own root: BIG → BIG
+        return jnp.where(found, root_val[j], v)
 
-def _value_lookup(uniq, root_val, values):
-    """Map values through (uniq → root_val); identity for missing values."""
-    j = jnp.clip(jnp.searchsorted(uniq, values), 0, uniq.shape[0] - 1).astype(I32)
-    return jnp.where(uniq[j] == values, root_val[j], values)
+    return lookup, lambda v: find(v)[0], K, converged, ran
 
 
 def phase1_local(
@@ -237,6 +303,7 @@ def phase1_local(
     pool_mask = jnp.concatenate([nm, nm, om])
     P = pool_stub.shape[0]
     hook1, hook2 = _hook_budgets(caps, P, touch.mask.shape[0])
+    space = caps.stub_space
 
     # ------------------------------------------------------------------
     # 2. pair per vertex: sort by (vertex, stub), pair consecutive
@@ -266,13 +333,16 @@ def phase1_local(
     universe = jnp.concatenate(
         [jnp.where(sm, sc, BIG), jnp.where(touch.mask, touch.comp, BIG)]
     )
-    uniq, root_val, cc_ok, hook_ran = _cc_hook_jump(
+    roots, comp_seg, n_seg, cc_ok, hook_ran = _cc_hook_jump(
         pr_ca, pr_cb, pr_mask, universe, hook1, static=caps.static_splice,
+        space=space,
     )
-    open_comp = _value_lookup(uniq, root_val, jnp.where(left_mask, sc, BIG))
-    pair_comp = _value_lookup(uniq, root_val, pr_ca)
-    touch_comp = _value_lookup(uniq, root_val,
-                               jnp.where(touch.mask, touch.comp, BIG))
+    # the id space drops ids at or past its end: count one as overflow
+    ids_ok = jnp.all((universe < space) | (universe == BIG)) if space \
+        else jnp.array(True)
+    open_comp = roots(jnp.where(left_mask, sc, BIG))
+    pair_comp = roots(pr_ca)
+    touch_comp = roots(jnp.where(touch.mask, touch.comp, BIG))
 
     # ------------------------------------------------------------------
     # 4. unified pair table (this level's pairs + inherited touch pairs)
@@ -293,18 +363,40 @@ def phase1_local(
     PC = q_s1.shape[0]
     q_c_pre = q_c          # pre-splice comps of the compacted pair table
 
-    oc = bounded.sort(open_comp)  # sorted open comps (BIG-padded)
+    # the open comps as a set: a member_table over the stub space, or
+    # sorted (BIG-padded) for the search
+    if space:
+        oc = member_table(open_comp, space)
 
-    def is_path(comps, oc_sorted):
-        j = jnp.clip(jnp.searchsorted(oc_sorted, comps), 0,
-                     oc_sorted.shape[0] - 1).astype(I32)
-        return (oc_sorted[j] == comps) & (comps < BIG)
+        def is_path(comps, oc):
+            return _take(oc, comps, 0) > 0
+    else:
+        oc = bounded.sort(open_comp)
+
+        def is_path(comps, oc):
+            return _searcher(oc)(comps)[1] & (comps < BIG)
+
+    def relabeler(mfrom, mto):
+        """(comp relabel, open-set relabel) for the map mfrom → mto;
+        ``mfrom`` holds each comp once (one rotation per comp a round)."""
+        if space:
+            table = relabel_table(mfrom, mto, space)
+            return (lambda vals: _take(table, vals, vals),
+                    lambda oc: member_table(jnp.where(oc, table, BIG), space))
+        mo = bounded.argsort(mfrom)
+        find, to = _searcher(mfrom[mo]), mto[mo]
+
+        def relabel(vals):
+            j, found = find(vals)
+            return jnp.where(found, to[j], vals)
+
+        return relabel, lambda oc: bounded.sort(relabel(oc))
 
     # ------------------------------------------------------------------
     # 5. splice rounds
     # ------------------------------------------------------------------
     def splice_round(state):
-        s2, cmp_, oc_sorted, _, rounds_left = state
+        s2, cmp_, oc, _, rounds_left = state
         vm = jnp.where(q_m, q_v, BIG)
         order2 = bounded.lexsort((cmp_, vm))  # H-E1': no s1 tiebreak
         gv, gc = vm[order2], cmp_[order2]
@@ -316,16 +408,16 @@ def phase1_local(
         rep = gm & ~dup & (gv < BIG)
         seg = _seg_starts(gv)
         n = gv.shape[0]
-        gpath = is_path(gc, oc_sorted) & rep
+        gpath = is_path(gc, oc) & rep
         n_rep = jax.ops.segment_sum(rep.astype(I32), seg, num_segments=n)
         n_cyc = jax.ops.segment_sum((rep & ~gpath).astype(I32), seg,
                                     num_segments=n)
         cand = rep & (n_rep[seg] >= 2) & (n_cyc[seg] >= 1)
         # each comp votes for its min candidate vertex
-        K = uniq.shape[0]
-        ci = jnp.clip(jnp.searchsorted(uniq, gc), 0, K - 1).astype(I32)
-        vote = jax.ops.segment_min(jnp.where(cand, gv, BIG), ci, num_segments=K)
-        voted = cand & (vote[ci] == gv)
+        ci = comp_seg(gc)
+        vote = jax.ops.segment_min(jnp.where(cand, gv, BIG), ci,
+                                   num_segments=n_seg)
+        voted = cand & (_take(vote, ci, BIG) == gv)
         # at most one path per vertex: cycles + the min-comp voted path
         pthmin = jax.ops.segment_min(
             jnp.where(voted & gpath, gc, BIG), seg, num_segments=n
@@ -352,18 +444,9 @@ def phase1_local(
         did = jnp.zeros_like(q_m).at[orig].set(hm)
         s2_new = jnp.where(did, s2_new, s2)
         # comp relabel map (from → min comp at its rotation vertex)
-        mfrom = jnp.where(hm, hc, BIG)
-        mto = jnp.where(hm, rot_c, BIG)
-        mo = bounded.argsort(mfrom)
-        mfrom, mto = mfrom[mo], mto[mo]
-
-        def relabel(vals):
-            j = jnp.clip(jnp.searchsorted(mfrom, vals), 0, n - 1).astype(I32)
-            return jnp.where(mfrom[j] == vals, mto[j], vals)
-
-        cmp_new = relabel(cmp_)
-        oc_new = bounded.sort(relabel(oc_sorted))
-        return s2_new, cmp_new, oc_new, changed, rounds_left - 1
+        relabel, reopen = relabeler(jnp.where(hm, hc, BIG),
+                                    jnp.where(hm, rot_c, BIG))
+        return s2_new, relabel(cmp_), reopen(oc), changed, rounds_left - 1
 
     def cond(state):
         return state[3] & (state[4] > 0)
@@ -391,15 +474,16 @@ def phase1_local(
     # (from → min of merged set), so CC over (pre-splice comp → final comp)
     # pairs has the final label as its min — a single hook/jump pass maps
     # every original comp to its final id.
-    uniq3, root3, cc3_ok, hook3_ran = _cc_hook_jump(
+    roots3, _, _, cc3_ok, hook3_ran = _cc_hook_jump(
         q_c_pre,
         q_c,
         q_m,
         jnp.concatenate([universe, jnp.where(q_m, q_c, BIG)]),
         hook2,
         static=caps.static_splice,
+        space=space,
     )
-    open_comp_final = _value_lookup(uniq3, root3, open_comp)
+    open_comp_final = roots3(open_comp)
 
     (o_stub, o_vert, o_la, o_comp), o_mask, open_of = _compact(
         (jnp.where(left_mask, ss, BIG), jnp.where(left_mask, sv, BIG),
@@ -431,7 +515,8 @@ def phase1_local(
         & jnp.concatenate([jnp.ones((1,), bool), live[1:] != live[:-1]])
     )
 
-    flags = jnp.stack([cc_ok & cc3_ok, splice_ok, ~(open_of | touch_of)])
+    flags = jnp.stack([cc_ok & cc3_ok, splice_ok,
+                       ~(open_of | touch_of) & ids_ok])
     return Phase1Out(
         opens=new_opens,
         touch=new_touch,

@@ -486,10 +486,12 @@ def test_adaptive_session_upgrades_and_stays_byte_equal():
         mb = MicroBatcher(solver, max_batch=2, deadline_s=0.0,
                           autotuner=tuner)
 
-        # cold session start: nothing warmed, first flushes run at B=1
+        # cold session start: nothing warmed, first flushes run at B=1.
+        # submit() hands back whatever the pipeline already completed
+        done = {}
         for i in (0, 1):
-            mb.submit(i, group[i])
-        done = dict(mb.drain())
+            done.update(mb.submit(i, group[i]))
+        done.update(mb.drain())
         assert list(mb.flushes.recent) == [1, 1], mb.flushes.hist
         # the flush histogram drove a B=2 prewarm order onto the
         # background compile service; wait for it to land
@@ -502,7 +504,7 @@ def test_adaptive_session_upgrades_and_stays_byte_equal():
         # mid-session upgrade: the same bucket's next quota flush now
         # dispatches one B=2 program
         for i in (2, 3):
-            mb.submit(i, group[i])
+            done.update(mb.submit(i, group[i]))
         done.update(mb.drain())
         assert list(mb.flushes.recent) == [1, 1, 2], mb.flushes.hist
         assert done[2].cache.batch == 2

@@ -115,7 +115,7 @@ def run_phase1_whole_graph(g):
         lav=jnp.zeros(E, jnp.int32),
         mask=jnp.ones(E, bool),
     )
-    caps = Phase1Caps(open_cap=8, touch_cap=8)
+    caps = Phase1Caps(open_cap=8, touch_cap=8, stub_space=2 * E)
     return jax.jit(phase1_local, static_argnames="caps")(
         new, empty_open(8), empty_touch(8), jnp.int32(0), caps
     )

@@ -78,7 +78,7 @@ def test_static_unrolls_report_their_fixed_counts():
                    lau=jnp.zeros(E, jnp.int32), lav=jnp.zeros(E, jnp.int32),
                    mask=jnp.ones(E, bool))
     caps = Phase1Caps(open_cap=8, touch_cap=8, splice_rounds=6,
-                      static_splice=True)
+                      static_splice=True, stub_space=2 * E)
     out = jax.jit(phase1_local, static_argnames="caps")(
         new, empty_open(8), empty_touch(8), jnp.int32(0), caps)
     assert int(out.splice_rounds) == 6
