@@ -284,6 +284,7 @@ def audit_program(eng, pg, e_cap: int, batch: Optional[int] = None,
     import jax
 
     from ..core.engine import fused_collective_budget
+    from ..core.phase1 import forest_round_budget
 
     sharded = bool(getattr(eng, "sharded_phase3", False))
     if sharded:
@@ -356,9 +357,12 @@ def audit_program(eng, pg, e_cap: int, batch: Optional[int] = None,
         "a2a_per_level_modeled": modeled,
         "a2a_run_total_modeled": sum(modeled.values()) * eng.n_levels * n,
     }
-    # the ladder_rounds budgets bounding the straggler while-loops of the
-    # traced body (splice vote rotations + Phase 3 pivot splice)
+    # the budgets bounding the straggler while-loops of the traced body:
+    # Phase 1's splice forest (derived from the stub pool) and vote
+    # rotations, Phase 3's pivot splice (ladder_rounds)
     cost["round_budgets"] = {
+        "forest_rounds": forest_round_budget(
+            2 * caps.new_cap + caps.open_cap, caps.touch_cap),
         "splice_rounds": caps.splice_rounds,
         "phase3_rounds": caps.phase3_rounds,
         "while_eqns_traced": cen.get("while", 0),

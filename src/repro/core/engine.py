@@ -80,7 +80,8 @@ class EngineCaps:
     mate_ship_cap: int = 0    # per (src,dst) lane for mate writes on the
                               # fused path (0 → 2·pair-table width, safe)
     hook_rounds: int = 0
-    splice_rounds: int = 12
+    splice_rounds: int = 12   # Phase 1 splice vote rounds (open paths); the
+                              # splice forest's budget derives from the pool
     phase3_rounds: int = 64   # pivot-splice round budget of device Phase 3
     static_splice: bool = False
     p3v_cap: int = 0          # sharded Phase 3 per-device vertex-record
@@ -146,8 +147,9 @@ class StepOut(NamedTuple):
     log_s2: jnp.ndarray
     log_mask: jnp.ndarray
     flags: jnp.ndarray     # [n, 4] cc, splice, p1-overflow, ship-overflow
-    metrics: jnp.ndarray   # [n, 6] longs: remote, opens, touch, comps;
-                           #        Phase 1 hook and splice rounds run
+    metrics: jnp.ndarray   # [n, 7] longs: remote, opens, touch, comps;
+                           #        Phase 1 hook, splice vote and splice
+                           #        forest rounds run
 
 
 class FusedOut(NamedTuple):
@@ -164,7 +166,7 @@ class FusedOut(NamedTuple):
     circuit: jnp.ndarray   # [E] arrival stubs in walk order (replicated)
     mate: jnp.ndarray      # [2E] post-splice mate permutation (replicated)
     flags: jnp.ndarray     # [n, L, 4]
-    metrics: jnp.ndarray   # [n, L, 6] (``StepOut.metrics`` per level)
+    metrics: jnp.ndarray   # [n, L, 7] (``StepOut.metrics`` per level)
     phase3_ok: jnp.ndarray  # [] bool: pivot splice converged
     phase3_rounds: jnp.ndarray  # [] int32: pivot-splice rounds run
 
@@ -241,7 +243,7 @@ class PendingRun:
                 for b in range(mate.shape[0])
             ])
         # circuit [B, E], mate [B, 2E], flags [n, B, L, 4],
-        # metrics [n, B, L, 6], ok3 / rounds3 [B]
+        # metrics [n, B, L, 7], ok3 / rounds3 [B]
         if not flags.all():
             raise RuntimeError(
                 f"convergence/capacity flags failed: {flags.all((0, 2, 3))}"
@@ -362,7 +364,9 @@ def _route(dest: jnp.ndarray, mask: jnp.ndarray, fields, n: int, lane: int):
         buf = jnp.full((n * lane + 1,), BIG, dtype=f.dtype)
         buf = buf.at[flat].set(jnp.where(ok, f[order], BIG))
         outs.append(buf[:-1].reshape(n, lane))
-    bm = jnp.zeros((n * lane + 1,), bool).at[flat].set(ok)
+    # the mask scatters into int32: a TPU scatter into bool compiles to
+    # about 1.8 MB more program code
+    bm = jnp.zeros((n * lane + 1,), I32).at[flat].set(ok.astype(I32)) > 0
     return outs, bm[:-1].reshape(n, lane), overflow
 
 
@@ -806,7 +810,7 @@ class DistributedEngine:
                  3 * jnp.sum(out.opens.mask).astype(I32),
                  4 * jnp.sum(out.touch.mask).astype(I32),
                  4 * out.n_components,
-                 out.hook_rounds, out.splice_rounds]
+                 out.hook_rounds, out.splice_rounds, out.forest_rounds]
             )
             return nstate, out.log_s1, out.log_s2, out.log_mask, flags, metrics
 

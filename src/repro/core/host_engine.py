@@ -287,10 +287,14 @@ class HostEngine:
 
     def _splice(self, st: PartState) -> None:
         """Merge components sharing an owned vertex; cycles merge into
-        anything, ≤1 path per rotation (the paper keeps OB paths apart)."""
+        anything, ≤1 path per rotation (the paper keeps OB paths apart).
+
+        Each round with a merge joins two components at least, and there
+        are at most as many components as edges, so ``num_edges + 1``
+        rounds always suffice; a merge still left after them raises."""
         vert_set = np.zeros(self.pg.graph.num_vertices, dtype=bool)
         vert_set[st.vertices] = True
-        for _ in range(64):
+        for _ in range(self.pg.graph.num_edges + 1):
             labels = self._labels()
             idx = np.nonzero(self.mate >= 0)[0]
             s = idx[self.mate[idx] > idx]          # canonical stub per pair
@@ -330,6 +334,9 @@ class HostEngine:
                 merged_any = True
             if not merged_any:
                 return
+        raise RuntimeError(f"host splice of partition {st.pid} still "
+                           f"merging after {self.pg.graph.num_edges + 1} "
+                           f"rounds")
 
     def _refresh_touch(self, st: PartState, level: int) -> None:
         live = self.cut_eids[self.act_level[self.cut_eids] >= level]

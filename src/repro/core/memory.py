@@ -39,10 +39,11 @@ class PartitionState:
     components: int
     deferred_remote: int = 0  # copies parked on this (inactive) leaf host
     # device backend: Phase 1 loop rounds this partition ran at this
-    # level (hook/jump rounds of both CC calls; splice rounds); None on
-    # the host backend
+    # level (Borůvka rounds of both CC calls; splice vote rounds; splice
+    # spanning-forest rounds); None on the host backend
     hook_rounds: Optional[int] = None
     splice_rounds: Optional[int] = None
+    forest_rounds: Optional[int] = None
 
     @property
     def longs(self) -> int:
@@ -73,17 +74,25 @@ class LevelStats:
 
     @property
     def hook_rounds(self) -> Optional[List[int]]:
-        """Phase 1 hook/jump rounds per partition (None: host backend)."""
+        """Phase 1 CC rounds per partition (None: host backend)."""
         if any(s.hook_rounds is None for s in self.states):
             return None
         return [s.hook_rounds for s in self.states]
 
     @property
     def splice_rounds(self) -> Optional[List[int]]:
-        """Phase 1 splice rounds per partition (None: host backend)."""
+        """Phase 1 splice vote rounds per partition (None: host backend)."""
         if any(s.splice_rounds is None for s in self.states):
             return None
         return [s.splice_rounds for s in self.states]
+
+    @property
+    def forest_rounds(self) -> Optional[List[int]]:
+        """Phase 1 splice spanning-forest rounds per partition (None: host
+        backend)."""
+        if any(s.forest_rounds is None for s in self.states):
+            return None
+        return [s.forest_rounds for s in self.states]
 
     @property
     def average(self) -> float:

@@ -7,12 +7,15 @@ TPU-native replacement for the paper's sequential Hierholzer walk
      endpoints) per vertex — sort + parity pairing.  Odd leftovers are the
      OB path endpoints of Lemma 1; components with no leftovers are the
      EB/internal cycles of Lemma 2.
-  2. *label* components: hook+jump (Shiloach–Vishkin-style) connected
-     components over the component-merge graph induced by the new pairs.
+  2. *label* components: connected components over the component-merge
+     graph induced by the new pairs, by the Borůvka hook/jump rounds the
+     splice's spanning forest uses too.
   3. *splice* components sharing an owned vertex (Lemma 3 / MERGEINTO) by
-     mate rotations, with a voting scheme that gives each component at most
-     one rotation per round (safe concurrent merging); cycles merge into
-     anything, at most one path participates per rotation.
+     mate rotations: first every cycle along a spanning forest of the
+     cycles-and-vertices graph, in one pass (Borůvka rounds, their number
+     growing with the log of the cycle count, not with the degrees); then
+     the open paths by a voting scheme that gives each component at most
+     one rotation per round, at most one path per rotation.
 
 Everything is static-shape and jit-compatible: masked fixed-capacity
 tables, sort-based grouping, ``segment_min`` label propagation, and
@@ -45,8 +48,9 @@ BIG = jnp.iinfo(jnp.int32).max
 class Phase1Caps:
     open_cap: int           # max carried-forward path endpoints
     touch_cap: int          # max representative pairs at boundary vertices
-    hook_rounds: int = 0    # 0 → ceil(log2(comp universe)) + 2
-    splice_rounds: int = 12
+    hook_rounds: int = 0    # 0 → ceil(log2(comp universe)) + 1
+    splice_rounds: int = 12  # vote rounds (open paths); the spanning
+                             # forest's budget is forest_round_budget
     static_splice: bool = False  # unroll splice and hook rounds (roofline
                                  # analysis: while-loop bodies are
                                  # cost-counted once)
@@ -89,8 +93,9 @@ class Phase1Out(NamedTuple):
     log_mask: jnp.ndarray
     n_components: jnp.ndarray  # [] live components touching this partition
     flags: jnp.ndarray         # [3] bool: cc converged, splice converged, no overflow
-    hook_rounds: jnp.ndarray   # [] hook/jump rounds run, both CC calls
-    splice_rounds: jnp.ndarray  # [] splice rounds run
+    hook_rounds: jnp.ndarray   # [] Borůvka rounds run, both CC calls
+    splice_rounds: jnp.ndarray  # [] splice vote rounds run
+    forest_rounds: jnp.ndarray  # [] splice spanning-forest rounds run
 
 
 def pair_table_cap(pool: int, touch_cap: int) -> int:
@@ -101,24 +106,36 @@ def pair_table_cap(pool: int, touch_cap: int) -> int:
     return pool // 2 + touch_cap
 
 
+def _boruvka_budget(nodes: int) -> int:
+    """Round budget of a :func:`_spanning_forest` over ``nodes`` nodes:
+    Borůvka rounds at least halve the trees of each component, so
+    ``ceil(log2 nodes)`` rounds always suffice; one more for slack."""
+    return int(math.ceil(math.log2(max(2, nodes)))) + 1
+
+
 def _hook_budgets(caps: Phase1Caps, pool: int, touch_cap: int):
-    """Round budgets of Phase 1's two hook/jump CC calls.  A nonzero
-    ``caps.hook_rounds`` fixes both; else each is ``ceil(log2 K) + 2``
-    for its universe of K values: the component values (``pool +
-    touch_cap``) for the first, twice as many for the second (that
-    universe joined with the pair table's post-splice comps)."""
+    """Round budgets of Phase 1's two CC calls.  A nonzero
+    ``caps.hook_rounds`` fixes both; else each is the Borůvka budget of
+    its universe: the component values (``pool + touch_cap``) for the
+    first, twice as many for the second (that universe joined with the
+    pair table's post-splice comps)."""
     if caps.hook_rounds:
         return caps.hook_rounds, caps.hook_rounds
     k = pool + touch_cap
-    return (int(math.ceil(math.log2(max(2, k)))) + 2,
-            int(math.ceil(math.log2(max(2, 2 * k)))) + 2)
+    return _boruvka_budget(k), _boruvka_budget(2 * k)
 
 
 def hook_round_budget(caps: Phase1Caps, pool: int, touch_cap: int) -> int:
-    """Most hook/jump rounds one ``phase1_local`` call can report: the
-    sum of both CC calls' budgets (``pool`` = stub-pool width,
-    ``2·new_cap + open_cap``)."""
+    """Most CC rounds one ``phase1_local`` call can report: the sum of
+    both CC calls' budgets (``pool`` = stub-pool width, ``2·new_cap +
+    open_cap``)."""
     return sum(_hook_budgets(caps, pool, touch_cap))
+
+
+def forest_round_budget(pool: int, touch_cap: int) -> int:
+    """Round budget of the splice's spanning forest, whose nodes are the
+    cycles of the pair table (at most ``pair_table_cap`` of them)."""
+    return _boruvka_budget(pair_table_cap(pool, touch_cap))
 
 
 def _in_space(values, space: int):
@@ -188,88 +205,130 @@ def _seg_starts(sorted_keys, idx_dtype=I32):
     return bounded.cummax(jnp.where(newseg, idx, 0))
 
 
+def _jump(lab):
+    """Pointer jumping to the fixpoint: every label becomes its tree's
+    root."""
+    def body(st):
+        lab, _ = st
+        nlab = lab[lab]
+        return nlab, jnp.any(nlab != lab)
+
+    return jax.lax.while_loop(lambda st: st[1], body,
+                              (lab, jnp.array(True)))[0]
+
+
+def _spanning_forest(na, nb, emask, K: int, rounds: int,
+                     static: bool = False, pick: bool = True):
+    """A spanning forest of the graph on nodes ``[0, K)`` whose edges are
+    ``(na[i], nb[i])`` where ``emask[i]``, by Borůvka rounds.
+
+    Each round every tree with an edge to another tree picks the
+    lowest-indexed such edge and hooks its root onto the other end's
+    root; where two trees picked the same edge, the smaller root stays.
+    Edges carry distinct indices, so the picks close no cycle but those
+    two-tree ones, and the picked edges stay a forest.  Every tree with an
+    edge leaving it merges each round, so the trees of a component at
+    least halve: the forest is spanning after ``ceil(log2 T)`` rounds for
+    T trees at the start.  Pointer jumping (:func:`_jump`) makes every
+    label a root again before the next round.  Stops at the first round
+    that finds no edge between trees; ``static`` unrolls all ``rounds``
+    (the jumps inside a round stay a loop).
+
+    Returns ``(least, tree, converged, rounds run)``: ``least[x]`` is the
+    least node of ``x``'s tree, ``tree`` marks the picked edges (bool,
+    elementwise: no scatter into a narrow type; None without ``pick``,
+    for a caller that wants the components alone).
+    """
+    E = na.shape[0]
+    idx = jnp.arange(E, dtype=I32)
+    node = jnp.arange(K, dtype=I32)
+    na = jnp.where(emask, na, 0)    # a masked edge joins node 0 to itself
+    nb = jnp.where(emask, nb, 0)
+
+    def round_(lab, ea, eb, tree):
+        cand = jnp.where(ea != eb, idx, BIG)
+        best = jax.ops.segment_min(jnp.concatenate([cand, cand]),
+                                   jnp.concatenate([ea, eb]),
+                                   num_segments=K)
+        has = best < BIG
+        e = jnp.minimum(best, E - 1)
+        other = (ea ^ eb)[e] ^ node             # the picked edge's far root
+        mutual = has & (_take(best, other, BIG) == best)
+        lab = _jump(jnp.where(has & ~(mutual & (node < other)), other, lab))
+        if pick:
+            tree = tree | (best[ea] == idx) | (best[eb] == idx)
+        return lab, lab[na], lab[nb], tree
+
+    state = (node, na, nb, jnp.zeros((E,), bool) if pick else None)
+    if static:
+        for _ in range(rounds):
+            state = round_(*state)
+        ran = jnp.array(rounds, I32)
+    else:
+        def body(st):
+            nst = round_(*st[:4])
+            return nst + (jnp.any(nst[1] != nst[2]), st[5] + 1)
+
+        st = jax.lax.while_loop(
+            lambda st: st[4] & (st[5] < rounds), body,
+            state + (jnp.any(na != nb), jnp.array(0, I32)))
+        state, ran = st[:4], st[5]
+    lab, ea, eb, tree = state
+    least = jax.ops.segment_min(node, lab, num_segments=K)[lab]
+    return least, tree, ~jnp.any(ea != eb), ran
+
+
 def _cc_hook_jump(ca, cb, emask, universe, rounds: int,
                   static: bool = False, space: int = 0):
     """Min-label connected components over a value-keyed graph.
 
     Nodes are the values in ``universe`` ([K], BIG-padded); edges are
     (ca[i], cb[i]) where ``emask[i]``, each endpoint a universe value.
-    Returns ``(roots, seg, n_seg, converged, rounds run)``: ``roots``
-    maps values to their component's root *value* (identity for values
-    outside the universe, so BIG stays BIG); ``seg`` maps universe values
-    to distinct ids below ``n_seg`` (the splice vote's segments), and
-    everything else to ids the segment ops drop or that no vote reads.
+    Returns ``(roots, seg, n_seg, converged, rounds run, value)``:
+    ``roots`` maps values to their component's least *value* (identity
+    for values outside the universe, so BIG stays BIG); ``seg`` maps
+    universe values to distinct ids below ``n_seg`` (the splice's node
+    ids), in value order, and everything else to ids the segment ops drop
+    or that no vote reads; ``value`` maps a universe value's ``seg`` id
+    back.
 
     With ``space`` (every value below it) the nodes are the ids below
-    ``space`` themselves, each its own first label, so the roots are
-    read straight from the labels; an id outside the universe is an
-    isolated node that keeps its label.  Without it the nodes are the
-    slots of the sorted universe, found by binary search.  Min-label
-    order is id order in both, so both give the same roots, flag and
-    round count.
-
-    Runs at most ``rounds`` hook/jump/contract rounds and stops at the
-    first round that leaves ``(lab, ea, eb)`` unchanged: a round is a
-    function of that state alone, so every later round would repeat it
-    and the result equals the full ``rounds``.  ``static`` unrolls all
-    ``rounds`` instead (roofline analysis: a ``while_loop`` body is
-    cost-counted once).
+    ``space`` themselves; an id outside the universe is an isolated node.
+    Without it the nodes are the slots of the sorted universe, found by
+    binary search.  Node order is value order in both, so both give the
+    same roots, flag and round count.  The components are the trees of
+    :func:`_spanning_forest` (at most ``rounds`` Borůvka rounds; ``static``
+    unrolls them).
     """
     if space:
-        # a masked edge is a self-loop on id 0, whose label never moves
-        K = space
-        ia = jnp.where(emask, ca, 0)
-        ib = jnp.where(emask, cb, 0)
+        K, ia, ib = space, ca, cb
     else:
         K = universe.shape[0]
         uniq = bounded.sort(universe)
         find = _searcher(uniq)
-        ia = jnp.where(emask, find(ca)[0], K - 1)
-        ib = jnp.where(emask, find(cb)[0], K - 1)
-    lab = jnp.arange(K, dtype=I32)
-
-    def hook(lab, ea, eb):
-        m = jnp.minimum(lab[ea], lab[eb])
-        both = jnp.concatenate([m, m])
-        tgt = jnp.concatenate([ea, eb])
-        return jnp.minimum(lab, jax.ops.segment_min(both, tgt, num_segments=K))
-
-    def round_(lab, ea, eb):
-        lab = hook(lab, ea, eb)
-        lab = lab[lab]
-        lab = lab[lab]
-        # Borůvka-style edge contraction: relabel endpoints to super-nodes
-        # so the next hook propagates between contracted components —
-        # this is what makes convergence O(log K) instead of O(diameter).
-        return lab, lab[ea], lab[eb]
-
-    ea, eb = ia, ib
-    if static:
-        for _ in range(rounds):
-            lab, ea, eb = round_(lab, ea, eb)
-        ran = jnp.array(rounds, I32)
-    else:
-        def body(state):
-            lab, ea, eb, _, r = state
-            nlab, nea, neb = round_(lab, ea, eb)
-            changed = (jnp.any(nlab != lab) | jnp.any(nea != ea)
-                       | jnp.any(neb != eb))
-            return nlab, nea, neb, changed, r + 1
-
-        lab, ea, eb, _, ran = jax.lax.while_loop(
-            lambda st: st[3] & (st[4] < rounds), body,
-            (lab, ea, eb, jnp.array(True), jnp.array(0, I32)))
-    converged = jnp.all(hook(lab, ea, eb) == lab)
+        ia, ib = find(ca)[0], find(cb)[0]
+    least, _, converged, ran = _spanning_forest(ia, ib, emask, K, rounds,
+                                                static=static, pick=False)
     if space:
-        return (lambda v: _take(lab, v, v),
-                lambda v: _in_space(v, space), space, converged, ran)
-    root_val = uniq[lab]
+        return (lambda v: _take(least, v, v),
+                lambda v: _in_space(v, space), space, converged, ran,
+                lambda s: s)
+    root_val = uniq[least]
 
     def lookup(v):
         j, found = find(v)      # a BIG slot is its own root: BIG → BIG
         return jnp.where(found, root_val[j], v)
 
-    return lookup, lambda v: find(v)[0], K, converged, ran
+    return (lookup, lambda v: find(v)[0], K, converged, ran,
+            lambda s: uniq[s])
+
+
+def _next_marked(mark):
+    """For each position ``p``, the least marked position ``≥ p`` (BIG
+    where none): a reversed running minimum, no gather."""
+    n = mark.shape[0]
+    neg = jnp.where(mark, -jnp.arange(n, dtype=I32), -BIG)
+    return -bounded.cummax(neg[::-1])[::-1]
 
 
 def phase1_local(
@@ -328,12 +387,12 @@ def phase1_local(
     left_mask = sm & ~paired & (sv < BIG)
 
     # ------------------------------------------------------------------
-    # 3. component labels after pairing (hook + jump CC over comp values)
+    # 3. component labels after pairing (CC over comp values)
     # ------------------------------------------------------------------
     universe = jnp.concatenate(
         [jnp.where(sm, sc, BIG), jnp.where(touch.mask, touch.comp, BIG)]
     )
-    roots, comp_seg, n_seg, cc_ok, hook_ran = _cc_hook_jump(
+    roots, comp_seg, n_seg, cc_ok, hook_ran, seg_value = _cc_hook_jump(
         pr_ca, pr_cb, pr_mask, universe, hook1, static=caps.static_splice,
         space=space,
     )
@@ -393,11 +452,55 @@ def phase1_local(
         return relabel, lambda oc: bounded.sort(relabel(oc))
 
     # ------------------------------------------------------------------
-    # 5. splice rounds
+    # 5a. splice the cycles along a spanning forest, in one pass
+    # ------------------------------------------------------------------
+    # Nodes are the cycles; at each vertex its first cycle row (the
+    # anchor) has an edge to every other cycle row there.  A spanning
+    # forest of that graph, rotated at every vertex among the anchor and
+    # the rows its forest edges reach, makes each tree one circuit
+    # (Atallah & Vishkin, JCSS 1984): the chosen passages of a vertex
+    # belong to distinct circuits however the other vertices' rotations
+    # went, or the forest would hold a cycle.  Paths stay out; the vote
+    # rounds below give each merge at most one.
+    vm = jnp.where(q_m, q_v, BIG)
+    order2 = bounded.argsort(vm)
+    gv, gc, gs2 = vm[order2], q_c[order2], q_s2[order2]
+    n = gv.shape[0]
+    pos = jnp.arange(n, dtype=I32)
+    seg = _seg_starts(gv)
+    live = q_m[order2] & (gv < BIG)
+    path = live & is_path(gc, oc)
+    cyc = live & ~path
+    anchor = jax.ops.segment_min(jnp.where(cyc, pos, BIG), seg,
+                                 num_segments=n)[seg]
+    anchor_c = gc[jnp.minimum(anchor, n - 1)]
+    least, tree, forest_ok, forest_ran = _spanning_forest(
+        comp_seg(anchor_c), comp_seg(gc), cyc & (gc != anchor_c), n_seg,
+        forest_round_budget(P, touch.mask.shape[0]),
+        static=caps.static_splice)
+    used = jax.ops.segment_max(tree.astype(I32), seg, num_segments=n)
+    act = tree | ((used[seg] > 0) & (pos == anchor))
+    # rotate each vertex's act rows circularly, in row order
+    first = _next_marked(act)
+    nxt = jnp.concatenate([first[1:], jnp.full((1,), BIG, I32)])
+    nxt_in = (nxt < n) & (gv[jnp.minimum(nxt, n - 1)] == gv)
+    rot = gs2[jnp.minimum(jnp.where(nxt_in, nxt, first[seg]), n - 1)]
+    q_s2 = q_s2.at[jnp.where(act, order2, n)].set(rot, mode="drop")
+    # a cycle's comp becomes its tree's least comp (comps are min member
+    # stubs, so that is the circuit's); paths keep theirs
+    node = comp_seg(q_c)
+    q_c = jnp.where(q_c < BIG, seg_value(_take(least, node, 0)), q_c)
+    # after a spanning forest no vertex holds two cycles: the votes start
+    # only where a cycle meets a path (its budget always suffices, so
+    # ``forest_ok`` is a flag, like the CC's)
+    has_path = jax.ops.segment_max(path.astype(I32), seg, num_segments=n)
+    pending = jnp.any(cyc & (has_path[seg] > 0))
+
+    # ------------------------------------------------------------------
+    # 5b. vote rounds: the open paths
     # ------------------------------------------------------------------
     def splice_round(state):
         s2, cmp_, oc, _, rounds_left = state
-        vm = jnp.where(q_m, q_v, BIG)
         order2 = bounded.lexsort((cmp_, vm))  # H-E1': no s1 tiebreak
         gv, gc = vm[order2], cmp_[order2]
         gs2 = s2[order2]
@@ -438,11 +541,10 @@ def phase1_local(
         minc = jax.ops.segment_min(jnp.where(hm, hc, BIG), hstart, num_segments=n)
         rot_c = jnp.where(hm, minc[hstart], hc)
         changed = jnp.any(hm)
-        # single unsort: active-space position p ↦ original index order2[o4[p]]
+        # single unsort of the rotated rows: active-space position p ↦
+        # original index order2[o4[p]] (int32 only: no bool scatter)
         orig = order2[o4]
-        s2_new = jnp.zeros_like(s2).at[orig].set(rot_s2)
-        did = jnp.zeros_like(q_m).at[orig].set(hm)
-        s2_new = jnp.where(did, s2_new, s2)
+        s2_new = s2.at[jnp.where(hm, orig, n)].set(rot_s2, mode="drop")
         # comp relabel map (from → min comp at its rotation vertex)
         relabel, reopen = relabeler(jnp.where(hm, hc, BIG),
                                     jnp.where(hm, rot_c, BIG))
@@ -451,8 +553,7 @@ def phase1_local(
     def cond(state):
         return state[3] & (state[4] > 0)
 
-    init = (q_s2, q_c, oc, jnp.array(True),
-            jnp.array(caps.splice_rounds, I32))
+    init = (q_s2, q_c, oc, pending, jnp.array(caps.splice_rounds, I32))
     if caps.static_splice:
         state = init
         for _ in range(caps.splice_rounds):
@@ -464,7 +565,7 @@ def phase1_local(
         q_s2, q_c, oc, still_changing, left = jax.lax.while_loop(
             cond, splice_round, init
         )
-        splice_ok = ~still_changing
+        splice_ok = ~still_changing & forest_ok
         splice_ran = caps.splice_rounds - left
 
     # ------------------------------------------------------------------
@@ -472,9 +573,9 @@ def phase1_local(
     # ------------------------------------------------------------------
     # Recover per-stub open comps: splice relabels are strictly decreasing
     # (from → min of merged set), so CC over (pre-splice comp → final comp)
-    # pairs has the final label as its min — a single hook/jump pass maps
+    # pairs has the final label as its min — a single CC pass maps
     # every original comp to its final id.
-    roots3, _, _, cc3_ok, hook3_ran = _cc_hook_jump(
+    roots3, _, _, cc3_ok, hook3_ran, _ = _cc_hook_jump(
         q_c_pre,
         q_c,
         q_m,
@@ -527,4 +628,5 @@ def phase1_local(
         flags=flags,
         hook_rounds=hook_ran + hook3_ran,
         splice_rounds=splice_ran,
+        forest_rounds=forest_ran,
     )

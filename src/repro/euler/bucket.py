@@ -212,26 +212,34 @@ def ladder_caps(caps: EngineCaps, e_cap: int, n_parts: int,
 
 
 def ladder_rounds(caps: EngineCaps, e_cap: int) -> EngineCaps:
-    """Schedule-derived straggler budgets for the two convergence loops
-    (ROADMAP: "batch stragglers under vmap").
+    """Schedule-derived straggler budgets for the splice loops (ROADMAP:
+    "batch stragglers under vmap").
 
-    Phase 1's splice voting and Phase 3's pivot splice are ``while_loop``s
-    that run a vmapped batch to its *slowest* member; their round budgets
-    bound that tail.  Both merges are vote-and-rotate contractions whose
-    round count grows with the log of the live component count, so the
-    budgets derive from the (quantized) table widths instead of the old
-    fixed 12/64: splice from the Phase 1 stub pool, Phase 3 from the
-    bucket's stub space ``2·e_cap`` (doubled, plus slack, because only the
-    globally-min pivot is *guaranteed* to fire each round).  Computed from
-    bucket-level quantities only, so same-bucket graphs share one budget
-    and the key never re-fragments.
+    Phase 1's splice and Phase 3's pivot splice are ``while_loop``s that
+    run a vmapped batch to its *slowest* member; their round budgets
+    bound that tail.  Phase 1 splices its cycles along a spanning forest
+    first: Borůvka rounds at least halve the trees, so ``ceil(log2 PC)``
+    rounds always suffice for a pair table of PC rows, and Phase 1
+    derives that budget from the stub pool itself
+    (:func:`repro.core.phase1.forest_round_budget`; whatever the degrees
+    or the edge order).  Its vote rounds then merge only open paths (at
+    P > 1 merge levels; at P=1 there is none), where a path meets a
+    cycle: ``splice_rounds`` is their budget, 10-16 from the pool as
+    before.  Phase 3's budget derives from the bucket's stub space
+    ``2·e_cap`` (doubled, plus slack, because only the globally-min pivot
+    is *guaranteed* to fire each round).  Computed from bucket-level
+    quantities only, so same-bucket graphs share one budget and the key
+    never re-fragments.
 
     >>> from repro.core.engine import EngineCaps
+    >>> from repro.core.phase1 import forest_round_budget
     >>> c = EngineCaps(edge_cap=96, park_cap=32, ship_cap=32, new_cap=96,
     ...                open_cap=32, touch_cap=64)
     >>> r = ladder_rounds(c, 128)
     >>> r.splice_rounds, r.phase3_rounds
     (11, 24)
+    >>> forest_round_budget(2 * c.new_cap + c.open_cap, c.touch_cap)
+    9
     """
     pool = 2 * caps.new_cap + caps.open_cap + caps.touch_cap
     splice = min(16, max(10, math.ceil(math.log2(max(2, pool))) + 2))

@@ -110,9 +110,10 @@ class EulerResult:
         """Normalize the device engine's per-level ``[n, 4]`` Int64-count
         arrays (``[2·parked, 3·opens, 4·touch, 4·components]`` per
         partition) into the same :class:`LevelStats` the host engine
-        reports, so both backends expose one metrics shape.  A fifth and
-        sixth column, where present, are the Phase 1 hook and splice
-        rounds the partition ran (``[n, 6]``, the engine's layout)."""
+        reports, so both backends expose one metrics shape.  A fifth,
+        sixth and seventh column, where present, are the Phase 1 hook,
+        splice vote and splice forest rounds the partition ran (``[n, 7]``,
+        the engine's layout)."""
         out: List[LevelStats] = []
         for lvl, m in enumerate(metrics_per_level):
             m = np.asarray(m)
@@ -126,6 +127,7 @@ class EulerResult:
                     components=int(row[3]) // 4,
                     hook_rounds=int(row[4]) if len(row) > 4 else None,
                     splice_rounds=int(row[5]) if len(row) > 5 else None,
+                    forest_rounds=int(row[6]) if len(row) > 6 else None,
                 )
                 for pid, row in enumerate(m)
             ]

@@ -147,6 +147,7 @@ def _loop_counters(res: EulerResult) -> dict:
     splice rounds."""
     return {"hook_rounds": [ls.hook_rounds for ls in res.levels],
             "splice_rounds": [ls.splice_rounds for ls in res.levels],
+            "forest_rounds": [ls.forest_rounds for ls in res.levels],
             "phase3_rounds": res.phase3_rounds}
 
 

@@ -14,7 +14,8 @@ import pytest
 from conftest import run_with_devices
 from repro import obs
 from repro.core.phase1 import (NewEdges, Phase1Caps, empty_open, empty_touch,
-                               hook_round_budget, phase1_local)
+                               forest_round_budget, hook_round_budget,
+                               phase1_local)
 from repro.euler import EulerSolver
 from repro.graphgen.eulerize import eulerian_rmat
 from test_core_euler import run_phase1_whole_graph, small_graph
@@ -22,18 +23,26 @@ from test_core_euler import run_phase1_whole_graph, small_graph
 
 def counters(res):
     return ([ls.hook_rounds for ls in res.levels],
-            [ls.splice_rounds for ls in res.levels], res.phase3_rounds)
+            [ls.splice_rounds for ls in res.levels], res.phase3_rounds,
+            [ls.forest_rounds for ls in res.levels])
 
 
 def check_bounds(res, caps):
-    """Every count is at least 1 and at most its static bound."""
+    """Every count lies within its static bound: CC rounds at least 2
+    (at P=1 the pairs join the stubs' comps and the splice relabels some,
+    so each CC call has an edge to hook), Phase 3 at least 1; the
+    splice's vote and forest loops may run none (no path meets a cycle;
+    no two cycles share a vertex)."""
     pool = 2 * caps.new_cap + caps.open_cap
     hook_max = hook_round_budget(caps.phase1(), pool, caps.touch_cap)
-    hooks, splices, p3 = counters(res)
+    forest_max = forest_round_budget(pool, caps.touch_cap)
+    hooks, splices, p3, forests = counters(res)
     for level in hooks:
         assert all(2 <= h <= hook_max for h in level), (level, hook_max)
     for level in splices:
-        assert all(1 <= s <= caps.splice_rounds for s in level), level
+        assert all(0 <= s <= caps.splice_rounds for s in level), level
+    for level in forests:
+        assert all(0 <= f <= forest_max for f in level), (level, forest_max)
     assert 1 <= p3 <= caps.phase3_rounds
 
 
@@ -54,18 +63,20 @@ def test_counters_agree_fused_eager_and_stay_in_bounds(graphs):
 
 def test_batch_member_counters_match_solo_solves(graphs):
     solver = EulerSolver(n_parts=1, trace=obs.NullTraceLog())
-    solo = [counters(solver.solve(g)) for g in graphs]
-    batched = [counters(r) for r in solver.solve_batch(graphs)]
+    # seed 2's cycles share no vertex (no forest round); seed 3's do
+    pair = graphs + [eulerian_rmat(8, avg_degree=5, seed=2)]
+    solo = [counters(solver.solve(g)) for g in pair]
+    batched = [counters(r) for r in solver.solve_batch(pair)]
     assert batched == solo
     # the members differ, so each kept its own count under vmap
-    assert solo[0] != solo[1]
+    assert solo[0] != solo[2]
 
 
 def test_host_backend_has_no_device_counters(graphs):
     res = EulerSolver(n_parts=2, backend="host").solve(graphs[0])
     assert res.phase3_rounds is None
     assert all(ls.hook_rounds is None and ls.splice_rounds is None
-               for ls in res.levels)
+               and ls.forest_rounds is None for ls in res.levels)
 
 
 def test_static_unrolls_report_their_fixed_counts():
@@ -83,8 +94,11 @@ def test_static_unrolls_report_their_fixed_counts():
         new, empty_open(8), empty_touch(8), jnp.int32(0), caps)
     assert int(out.splice_rounds) == 6
     assert int(out.hook_rounds) == hook_round_budget(caps, 2 * E + 8, 8)
-    # the while loops stop at their fixpoint, within the same budgets
-    assert 1 <= int(loops.splice_rounds) <= Phase1Caps(8, 8).splice_rounds
+    assert int(out.forest_rounds) == forest_round_budget(2 * E + 8, 8)
+    # the while loops stop at their fixpoint, within the same budgets: no
+    # open path, so the forest splices every cycle and no vote runs
+    assert int(loops.splice_rounds) == 0
+    assert 1 <= int(loops.forest_rounds) < int(out.forest_rounds)
     assert 2 <= int(loops.hook_rounds) < int(out.hook_rounds)
 
 
@@ -97,17 +111,23 @@ def test_p4_counters_match_the_eager_oracle():
         from repro.graphgen.eulerize import eulerian_rmat
         solver = EulerSolver(n_parts=4, trace=obs.NullTraceLog())
         assert solver.sharded_phase3
+        votes = 0
         for seed in (3, 4):
             g = eulerian_rmat(8, avg_degree=5, seed=seed)
             f = solver.solve(g).validate()
             e = solver.solve(g, fused=False).validate()
             cf = ([l.hook_rounds for l in f.levels],
-                  [l.splice_rounds for l in f.levels], f.phase3_rounds)
+                  [l.splice_rounds for l in f.levels], f.phase3_rounds,
+                  [l.forest_rounds for l in f.levels])
             ce = ([l.hook_rounds for l in e.levels],
-                  [l.splice_rounds for l in e.levels], e.phase3_rounds)
+                  [l.splice_rounds for l in e.levels], e.phase3_rounds,
+                  [l.forest_rounds for l in e.levels])
             assert cf == ce, (cf, ce)
             assert all(len(h) == 4 for h in cf[0])
+            votes += sum(map(sum, cf[1]))
             print(cf)
+        # seed 3 has a vertex where a cycle meets an open path
+        assert votes > 0
     """, n=4)
     assert len(out.strip().splitlines()) == 2
 
@@ -144,10 +164,11 @@ def test_solve_span_tree_shares_one_req(graphs):
     (prep,) = [s for s in spans if s["name"] == "prepare"]
     assert res.timings["prepare_s"] == prep["dur_s"]
     # the counters ride on the root span
-    hooks, splices, p3 = counters(res)
+    hooks, splices, p3, forests = counters(res)
     assert root["attrs"]["hook_rounds"] == hooks
     assert root["attrs"]["splice_rounds"] == splices
     assert root["attrs"]["phase3_rounds"] == p3
+    assert root["attrs"]["forest_rounds"] == forests
     # children lie inside the root, in order
     t0, t1 = root["t0"], root["t0"] + root["dur_s"]
     kids = sorted((s for s in spans if s["parent"] == root["id"]),

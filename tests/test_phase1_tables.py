@@ -202,31 +202,52 @@ def test_phase1_table_path_equals_search_whole_graph(seed):
     E = g.num_edges
     new = _new_edges(g, np.arange(E), np.zeros(g.num_vertices, np.int64), E)
     caps = Phase1Caps(open_cap=8, touch_cap=8)
-    _, rounds = _both_paths((new, empty_open(8), empty_touch(8),
-                             jnp.int32(0)), caps, E)
-    assert any(n for n, _ in rounds)         # some round rotated
+    out, rounds = _both_paths((new, empty_open(8), empty_touch(8),
+                               jnp.int32(0)), caps, E)
+    # the cycles were spliced: along the forest (no path, so no vote)
+    assert int(out.forest_rounds) >= 1 and not rounds
 
 
-@pytest.mark.parametrize("seed", [0, 1])
-def test_phase1_table_path_equals_search_partitioned(seed):
+def _partitioned(seed):
     """Both level-0 partitions of a two-way split, then the merge level
-    that takes their opens and touch pairs and the cut edges."""
+    that takes their opens and touch pairs and the cut edges, each on both
+    paths.  Returns, per partition and level, the vote rounds' active
+    ``mfrom`` rows and the forest rounds."""
     g = eulerian_rmat(8, avg_degree=5, seed=seed)
     pg = partition_graph(g, partition_vertices(g, 2, seed=seed))
     tree, act, la, cut_ids, _ = DistributedEngine.plan(pg)
     assert tree.height == 1 and len(cut_ids)
     E, cap = g.num_edges, 512
     caps = Phase1Caps(open_cap=cap, touch_cap=cap)
-    outs, rotated = [], 0
+    outs, level0 = [], []
     for p in pg.parts:
         new = _new_edges(g, p.local_eids, la, E)
         out, rounds = _both_paths((new, empty_open(cap), empty_touch(cap),
                                    jnp.int32(0)), caps, E)
         outs.append(out)
-        rotated += sum(n for n, _ in rounds)
+        level0.append((sum(n for n, _ in rounds), int(out.forest_rounds)))
     opens = _merge([o.opens for o in outs], cap, OpenTable)
     touch = _merge([o.touch for o in outs], cap, TouchTable)
     assert np.asarray(opens.mask).any() and np.asarray(touch.mask).any()
     new = _new_edges(g, cut_ids[act[cut_ids] == 0], la, E)
-    _, rounds = _both_paths((new, opens, touch, jnp.int32(1)), caps, E)
-    assert rotated + sum(n for n, _ in rounds)
+    out, rounds = _both_paths((new, opens, touch, jnp.int32(1)), caps, E)
+    return level0, (sum(n for n, _ in rounds), int(out.forest_rounds))
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_phase1_table_path_equals_search_partitioned(seed):
+    """The merge level's cycles meet at its vertices: it splices them
+    along the forest.  (No level-0 vertex of these splits holds both a
+    cycle and a path, so no vote round runs here; the next test's splits
+    have one.)"""
+    _, merge = _partitioned(seed)
+    assert merge[1] >= 1, merge
+
+
+@pytest.mark.parametrize("seed", [3, 4, 5])
+def test_phase1_table_path_equals_search_where_a_cycle_meets_a_path(seed):
+    """In these splits a level-0 partition holds a vertex where a cycle
+    meets an open path, so the vote rounds rotate there, and the spy of
+    ``_both_paths`` saw their ``mfrom`` rows unique."""
+    level0, _ = _partitioned(seed)
+    assert sum(rows for rows, _ in level0) > 0, level0
