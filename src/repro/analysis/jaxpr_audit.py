@@ -6,7 +6,7 @@ The engine publishes its collective schedule statically
 exactly one ``all_gather`` for the replicated device Phase 3, or — under
 ``sharded_phase3`` (DESIGN.md §11) — the ring schedule of
 :func:`repro.core.phase3.sharded_phase3_schedule` (9 ``ppermute``
-eqns, two of them in R-round doubling loops, 2 ``psum``, and at most one emission ``all_gather``, elided when
+eqns, two of them in R-round doubling loops, 3 ``psum``, and at most one emission ``all_gather``, elided when
 ``gather_circuit=False``); nothing else.  This module traces each
 ``(bucket, batch-width)`` program the solver would cache, walks the
 closed jaxpr, and fails if the compiled program communicates — or syncs

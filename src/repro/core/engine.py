@@ -298,7 +298,9 @@ def fused_collective_budget(n_levels: int, num_edges: Optional[int] = None,
     Phase 3 (``sharded_phase3=True``, needs ``num_edges``/``n_parts``)
     instead runs the ring schedule of
     :func:`repro.core.phase3.sharded_phase3_schedule` — 9
-    ``ppermute`` ring loops (2R+7 rings at run time) and 2 ``psum`` eqns, with the single
+    ``ppermute`` ring loops (R+1 rings at run time where the mate is one
+    circuit, 2R+1 more and 6 a splice round where it is not) and 3
+    ``psum`` eqns, with the single
     ``all_gather`` deferred to circuit emission (and elided entirely
     under ``gather_circuit=False``).  Nothing else may communicate —
     ``repro.analysis.jaxpr_audit`` walks the compiled jaxpr and fails the
@@ -873,9 +875,11 @@ class DistributedEngine:
             and scattered in.  Later-level writes overwrite earlier ones —
             exactly the host replay order — and within a level the pairs
             are device-disjoint, so the scatter is conflict-free;
-          · Phase 3 on-device: all_gather the mate shards, then the pivot
-            splice + list-rank emission (``phase3_device``), replicated
-            per device, XLA gather rounds as the doubling backend.
+          · Phase 3 on-device: all_gather the mate shards, then the
+            list-rank emission, with the pivot splice and a second rank
+            only where the rank's orbit misses edges (``phase3_device``),
+            replicated per device, XLA gather rounds as the doubling
+            backend.
 
         The program's outputs (circuit, mate, flags, metrics with the
         per-level loop rounds, Phase 3's convergence flag and rounds) are

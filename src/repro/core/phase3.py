@@ -8,8 +8,9 @@ vectorized — rather than the paper's sequential disk unroll.
 
 Both a NumPy (host/oracle) and a JAX (device) implementation live here;
 they share semantics and are cross-checked in tests.  The device path
-(:func:`splice_components_jnp` + :func:`circuit_from_mate_jnp` behind
-:func:`phase3_device`) is fully jittable and runs inside the fused engine
+(:func:`phase3_device`: the list rank first, then
+:func:`splice_components_jnp` and a second rank only where the ranked
+orbit misses edges) is fully jittable and runs inside the fused engine
 program (DESIGN.md §4): the scipy ``connected_components`` call becomes
 pointer-doubling min-label propagation over the cycle structure (the XLA
 gather rounds of ``repro.kernels.ref``, the same program on every backend)
@@ -70,24 +71,46 @@ def circuit_from_mate_jnp(mate: jnp.ndarray,
 
     Returns arrival stubs in walk order, padded with -1 where ``mate`` is
     invalid (padding slots).  Static shapes: output has ``len(mate)//2``
-    entries (E slots).  The doubling rounds are
-    :func:`repro.kernels.ref.pointer_double_rank_ref` under one
-    ``fori_loop``, so the program's size does not grow with the round count.
+    entries (E slots).
     """
+    dist, reach = _rank(mate, start_stub)
+    return emit_circuit(mate >= 0, dist, reach)
+
+
+@jax.named_scope("rank")
+def _rank(mate: jnp.ndarray,
+          start_stub: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """List ranking of the walk orbit that starts at ``start_stub``:
+    ``(dist, reach)``, each stub's distance to the halt stub and whether
+    it lies on that orbit.  The doubling rounds are
+    :func:`repro.kernels.ref.pointer_double_rank_ref` under one
+    ``fori_loop``, so the program's size does not grow with the round
+    count."""
     n_stubs = mate.shape[0]
     iota = jnp.arange(n_stubs, dtype=mate.dtype)
-    valid = mate >= 0
-    with jax.named_scope("rank"):
-        nxt = jnp.where(valid, mate ^ 1, iota)
-        t = mate[start_stub ^ 1]
-        ptr = nxt.at[t].set(t).astype(I32)
-        dist = jnp.ones(n_stubs, dtype=jnp.int32).at[t].set(0)
-        reach = jnp.zeros(n_stubs, dtype=I32).at[t].set(1)
-        rounds = int(np.ceil(np.log2(max(2, n_stubs)))) + 1
-        _, dist, reach = jax.lax.fori_loop(
-            0, rounds, lambda _, c: _kref.pointer_double_rank_ref(*c),
-            (ptr, dist, reach))
-    return emit_circuit(valid, dist, reach)
+    nxt = jnp.where(mate >= 0, mate ^ 1, iota)
+    t = mate[start_stub ^ 1]
+    ptr = nxt.at[t].set(t).astype(I32)
+    dist = jnp.ones(n_stubs, dtype=jnp.int32).at[t].set(0)
+    reach = jnp.zeros(n_stubs, dtype=I32).at[t].set(1)
+    rounds = int(np.ceil(np.log2(max(2, n_stubs)))) + 1
+    _, dist, reach = jax.lax.fori_loop(
+        0, rounds, lambda _, c: _kref.pointer_double_rank_ref(*c),
+        (ptr, dist, reach))
+    return dist, reach
+
+
+def _stubs_off_circuit(valid: jnp.ndarray, reach: jnp.ndarray) -> jnp.ndarray:
+    """Mated stubs whose edge the ranked orbit does not walk (int32).
+
+    On a perfect matching each cycle splits into a forward and a reverse
+    orbit, each holding one stub of every edge of the cycle.  So the
+    orbit walks every mated edge exactly when it holds half of the mated
+    stubs, and the mate is one closed walk exactly when this is 0.  A
+    count, not a per-edge test: it adds the least code to the program,
+    whose size the device's peak memory follows."""
+    on = jnp.sum((valid & (reach > 0)).astype(I32))
+    return jnp.sum(valid.astype(I32)) - 2 * on
 
 
 @jax.named_scope("emit")
@@ -310,9 +333,53 @@ def splice_components_jnp(
     return mate, ~still_changing, rounds - left
 
 
+def _rank_first(splice, rank, misses, mate):
+    """Phase 3's order, shared by the replicated and the sharded path.
+
+    Pass 0 ranks the mate as handed over.  Only where the ranked orbit
+    misses edges (``misses(reach)``) does pass 1 run ``splice`` (CC
+    labels plus the pivot-splice loop) and rank the spliced mate.  When
+    the orbit covers every edge the mate is one cycle, so the splice
+    would find no pivot with two components and change nothing: the
+    circuit is the one the splice-first order gives, byte for byte.
+
+    The splice sits in a ``while_loop`` that runs at most once, not in a
+    ``lax.cond``: under ``vmap`` a batched ``cond`` becomes a ``select``
+    that runs both branches, while the loop runs only when some graph of
+    the batch needs it.  The rank is traced once, in the pass loop's body.
+
+    Returns ``(mate', dist, reach, ok, splice_rounds_run)``; on the skip
+    ``ok`` is true and the rounds are 0.
+    """
+    def gated_splice(c):
+        m, _, _, _ = c
+        m, ok, ran = splice(m)
+        return m, ok, ran, jnp.array(False)
+
+    def one_pass(c):
+        m, ok, ran, _, _, pending, passes = c
+        m, ok, ran, _ = jax.lax.while_loop(
+            lambda s: s[3], gated_splice, (m, ok, ran, pending))
+        dist, reach = rank(m)
+        return m, ok, ran, dist, reach, misses(reach), passes + 1
+
+    def again(c):
+        passes = c[6]
+        return (passes == 0) | (c[5] & (passes < 2))
+
+    zero = jnp.zeros(mate.shape, I32)
+    init = (mate.astype(I32), jnp.array(True), jnp.array(0, I32), zero, zero,
+            jnp.array(False), jnp.array(0, I32))
+    mate, ok, ran, dist, reach, _, _ = jax.lax.while_loop(
+        again, one_pass, init)
+    return mate, dist, reach, ok, ran
+
+
 def phase3_device(mate: jnp.ndarray, stub_vertex: jnp.ndarray,
                   splice_rounds: int = 64):
-    """Full on-device Phase 3: pivot splice + list-rank emission.
+    """Full on-device Phase 3: list-rank emission, with the pivot splice
+    and a second rank only where the first orbit misses edges
+    (:func:`_rank_first`).
 
     Shared by the fused engine program (where it runs replicated inside the
     same shard_map as the level scan) and the eager oracle path (where it
@@ -325,11 +392,14 @@ def phase3_device(mate: jnp.ndarray, stub_vertex: jnp.ndarray,
     Returns ``(circuit [E], mate', splice_converged, splice_rounds_run)``.
     """
     valid = mate >= 0
-    mate2, ok, ran = splice_components_jnp(mate, stub_vertex, valid,
-                                           rounds=splice_rounds)
     start = jnp.argmax(valid).astype(I32)
-    circuit = circuit_from_mate_jnp(mate2, start)
-    return circuit, mate2, ok, ran
+    mate2, dist, reach, ok, ran = _rank_first(
+        lambda m: splice_components_jnp(m, stub_vertex, valid,
+                                        rounds=splice_rounds),
+        lambda m: _rank(m, start),
+        lambda reach: _stubs_off_circuit(valid, reach) > 0,
+        mate)
+    return emit_circuit(valid, dist, reach), mate2, ok, ran
 
 
 # ---------------------------------------------------------------------------
@@ -378,19 +448,26 @@ def sharded_phase3_schedule(num_edges: int, n_parts: int,
     """The sharded Phase 3's static collective schedule, counted in jaxpr
     *eqns* (ring loops trace one ppermute eqn each; the runtime executes
     each ``n_parts`` times per loop, and each doubling ring once per
-    round: 2R+7 rings in all).  Shared by the engine's published budget
+    round).  Shared by the engine's published budget
     (``fused_collective_budget``) and the analysis cost model so the two
     can never drift.
 
-      · CC doubling: one table-rotation ring inside the R-round loop;
+      · rank (traced once, in the pass loop of ``_rank_first``): 1
+        ring-min for the start stub, 1 ``psum`` fetching the halt stub's
+        mate, one rotation ring inside the R-round loop, and 1 ``psum``
+        of the mated stubs off the orbit's circuit;
+      · CC doubling (run only when that count is not 0): one
+        table-rotation ring inside the R-round loop;
       · pivot splice (inside the while body, traced once): 6 rings —
         record ship, vote scatter, vote readback, mate write, relabel
         scatter, relabel readback — plus 1 ``psum`` for the global
         `changed` flag;
-      · rank: 1 ring-min for the start stub, 1 ``psum`` fetching the halt
-        stub's mate, one rotation ring inside the R-round loop;
       · emission: 1 ``all_gather`` (elided when ``gather_circuit=False``,
         where the rank shards leave the program still sharded).
+
+    At run time a mate that is already one circuit runs R+1 rings (the
+    rank's); one that needs splicing runs 2R+1 more (CC, second rank)
+    and 6 for each splice round.
     """
     S = shard_width(num_edges, n_parts)
     total = n_parts * S
@@ -401,7 +478,7 @@ def sharded_phase3_schedule(num_edges: int, n_parts: int,
         "doubling_rounds": rounds,
         "splice_rings": 6,
         "ppermute": 2 + 6 + 1,
-        "psum": 2,
+        "psum": 3,
         "all_gather": 1 if gather_circuit else 0,
     }
 
@@ -633,7 +710,7 @@ def splice_components_sharded(
 def _rank_sharded(mate_sh: jnp.ndarray, axes,
                   n: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Sharded list ranking: the doubling loop of
-    :func:`circuit_from_mate_jnp` over rotating (ptr, dist, reach) table
+    :func:`_rank` over rotating (ptr, dist, reach) table
     shards.  Returns the local (dist, reach) slices."""
     S = mate_sh.shape[0]
     me = jax.lax.axis_index(axes).astype(I32)
@@ -686,7 +763,10 @@ def phase3_sharded(mate_sh: jnp.ndarray, sv_sh: jnp.ndarray, axes, n: int,
                    n_stubs: int, p3v_cap: int,
                    splice_rounds: int = 64,
                    gather_circuit: bool = True):
-    """Full sharded Phase 3 for one device's [S] stub shard.
+    """Full sharded Phase 3 for one device's [S] stub shard, in the
+    order of :func:`phase3_device` (:func:`_rank_first`): rank, then the
+    CC and pivot splice and a second rank only where the orbit misses
+    edges.  A shard holds both stubs of each of its edges (S is even).
 
     With ``gather_circuit=True`` (the default) the run's ONE
     ``all_gather`` happens here — at the very end, on the post-rank
@@ -698,9 +778,15 @@ def phase3_sharded(mate_sh: jnp.ndarray, sv_sh: jnp.ndarray, axes, n: int,
     :class:`PendingRun`) emits the circuit host-side from the fetched
     shards via the same :func:`emit_circuit` ordering.
     """
-    mate2_sh, ok, ran = splice_components_sharded(
-        mate_sh, sv_sh, axes, n, p3v_cap, rounds=splice_rounds)
-    dist_sh, reach_sh = _rank_sharded(mate2_sh, axes, n)
+    valid = mate_sh >= 0
+    mate2_sh, dist_sh, reach_sh, ok, ran = _rank_first(
+        lambda m: splice_components_sharded(m, sv_sh, axes, n, p3v_cap,
+                                            rounds=splice_rounds),
+        lambda m: _rank_sharded(m, axes, n),
+        # one psum: every device takes the same branch
+        lambda reach: jax.lax.psum(_stubs_off_circuit(valid, reach),
+                                    axes) > 0,
+        mate_sh)
     if not gather_circuit:
         return mate2_sh, dist_sh, reach_sh, ok, ran
     with jax.named_scope("emit"):

@@ -143,12 +143,15 @@ class PendingSolve:
 
 def _loop_counters(res: EulerResult) -> dict:
     """A fused solve's device loop counters, as its root span records
-    them: per-level lists of per-partition Phase 1 rounds, and Phase 3's
-    splice rounds."""
+    them: per-level lists of per-partition Phase 1 rounds, Phase 3's
+    splice rounds, and whether Phase 3 ran its CC and splice (1) or
+    found the mate one circuit already (0; the splice loop runs at least
+    one round whenever it is entered)."""
     return {"hook_rounds": [ls.hook_rounds for ls in res.levels],
             "splice_rounds": [ls.splice_rounds for ls in res.levels],
             "forest_rounds": [ls.forest_rounds for ls in res.levels],
-            "phase3_rounds": res.phase3_rounds}
+            "phase3_rounds": res.phase3_rounds,
+            "phase3_cc_runs": int(res.phase3_rounds > 0)}
 
 
 class EulerSolver:

@@ -345,13 +345,13 @@ def test_audit_golden_sharded_scale5():
         assert prog["violations"] == []
         cen, sched = prog["census"], prog["budget"]["phase3"]
         rounds = sched["doubling_rounds"]
-        # ring schedule: 9 ppermute eqns (2R+7 rings at run time: the
-        # two doubling rings each sit in one R-round loop), 2 psum, one
-        # emission gather
+        # ring schedule: 9 ppermute eqns (the two doubling rings each
+        # sit in one R-round loop), 3 psum (the halt stub, the edges off
+        # the rank's orbit, the splice's `changed`), one emission gather
         assert cen["ppermute"] == 9 == sched["ppermute"]
         assert sum(1 for ln, body in prog["scans"]
                    if ln == rounds and body.get("ppermute")) == 2
-        assert cen["psum"] == 2
+        assert cen["psum"] == 3 == sched["psum"]
         assert cen["all_gather"] == 1
         assert cen["all_to_all"] == prog["budget"]["all_to_all"]
         assert prog["cost"]["sharded"] is True

@@ -120,6 +120,12 @@ def test_random_per_vertex_pairing(seed):
     """Stress: arbitrary per-vertex stub pairing of an Eulerian graph —
     many components crossing at many pivots — must splice to one orbit."""
     g = eulerian_rmat(7, avg_degree=4, seed=seed)
+    check_both_splices(g, per_vertex_pairing(g))
+
+
+def per_vertex_pairing(g: Graph) -> np.ndarray:
+    """Pair each vertex's stubs in stub order: an arbitrary perfect
+    matching, whose cycles cross at many pivots."""
     sv = stub_vertices(g)
     n_stubs = 2 * g.num_edges
     order = np.argsort(sv, kind="stable")
@@ -135,7 +141,7 @@ def test_random_per_vertex_pairing(seed):
     mate = np.full(n_stubs, -1, dtype=np.int64)
     mate[a] = b
     mate[b] = a
-    check_both_splices(g, mate)
+    return mate
 
 
 def test_phase3_device_end_to_end():
@@ -165,3 +171,106 @@ def test_circuit_pallas_backend_byte_identical():
                                   jnp.int32(start))
     assert (np.asarray(c_jnp) == c_np).all()
     validate_circuit(g, np.asarray(c_jnp, dtype=np.int64))
+
+
+# ---------------------------------------------------------------------------
+# rank first: the CC and splice run only when the orbit misses edges
+# ---------------------------------------------------------------------------
+
+def splice_first(mate, sv):
+    """Phase 3 in the splice-first order: CC labels and pivot splice on
+    every mate, then the rank of the spliced mate."""
+    valid = mate >= 0
+    m2, ok, ran = splice_components_jnp(mate, sv, valid)
+    start = jnp.argmax(valid).astype(jnp.int32)
+    return circuit_from_mate_jnp(m2, start), m2, ok, ran
+
+
+CYCLE_SETS = {
+    "three_triangles": (7, [[0, 1, 2], [0, 3, 4], [0, 5, 6]]),
+    "five_cycles": (11, [[0, 1, 2], [0, 3, 4], [0, 5, 6], [0, 7, 8],
+                         [0, 9, 10]]),
+    "chain": (10, [[0, 1, 2], [1, 3, 4], [4, 5, 6], [6, 7, 8], [8, 9, 0]]),
+    "two_pivots": (8, [[0, 2, 1, 3], [0, 4, 1, 5], [0, 6, 1, 7]]),
+}
+
+
+def phase3_case(name, one_cycle):
+    """A multi-cycle mate (the cycle sets above, or a per-vertex pairing
+    of an RMAT graph), or with ``one_cycle`` the same graph's mate after
+    the host splice: one closed walk over every edge."""
+    if name == "rmat_pairing":
+        g = eulerian_rmat(7, avg_degree=4, seed=0)
+        mate = per_vertex_pairing(g)
+    else:
+        g, mate = graph_of_cycles(*CYCLE_SETS[name])
+    sv = stub_vertices(g)
+    if one_cycle:
+        mate = splice_components_np(mate, sv, mate >= 0)
+    return g, jnp.asarray(mate, jnp.int32), jnp.asarray(sv, jnp.int32)
+
+
+@pytest.mark.parametrize("one_cycle", [False, True],
+                         ids=["multi_cycle", "one_cycle"])
+@pytest.mark.parametrize("name", [*CYCLE_SETS, "rmat_pairing"])
+def test_rank_first_is_byte_identical_to_splice_first(name, one_cycle):
+    """The same circuit, mate and ``ok`` as the splice-first order; the
+    same splice rounds where the orbit misses edges, and 0 (CC and splice
+    skipped) where the mate is one circuit already."""
+    g, mate, sv = phase3_case(name, one_cycle)
+    circ, m2, ok, rounds = jax.jit(phase3_device)(mate, sv)
+    c_old, m_old, ok_old, r_old = jax.jit(splice_first)(mate, sv)
+    assert np.array_equal(np.asarray(circ), np.asarray(c_old))
+    assert np.array_equal(np.asarray(m2), np.asarray(m_old))
+    assert bool(ok) and bool(ok_old)
+    if one_cycle:
+        assert int(rounds) == 0 and int(r_old) == 1
+        assert np.array_equal(np.asarray(m2), np.asarray(mate))
+    else:
+        assert int(rounds) == int(r_old) >= 2
+    validate_circuit(g, np.asarray(circ, dtype=np.int64))
+
+
+def test_batched_phase3_matches_each_graph_alone():
+    """A one-circuit mate batched with a multi-cycle one: the batch gives,
+    graph by graph, what the unbatched calls give (the multi-cycle member
+    runs the splice; the other keeps its skip)."""
+    cases = [phase3_case("three_triangles", one) for one in (True, False)]
+    mates = jnp.stack([m for _, m, _ in cases])
+    svs = jnp.stack([sv for _, _, sv in cases])
+    batched = jax.jit(jax.vmap(phase3_device))(mates, svs)
+    for b, (_, mate, sv) in enumerate(cases):
+        alone = jax.jit(phase3_device)(mate, sv)
+        for x, y in zip(batched, alone):
+            assert np.array_equal(np.asarray(x[b]), np.asarray(y))
+    assert [int(r) for r in batched[3]] == [0, 2]
+
+
+def _loops(jaxpr, depth=0, out=None):
+    """``(primitive, while-depth, name stack)`` of every loop eqn."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("scan", "while"):
+            out.append((name, depth, str(eqn.source_info.name_stack)))
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _loops(sub, depth + (name == "while"), out)
+    return out
+
+
+def test_batched_cc_stays_inside_a_loop():
+    """Under ``vmap`` the CC doubling loop sits in the splice's gate loop,
+    one ``while`` deeper than the rank: it is not lifted into a ``select``
+    that runs it for every batch."""
+    _, mate, sv = phase3_case("three_triangles", True)
+    jaxpr = jax.make_jaxpr(jax.vmap(phase3_device))(
+        jnp.stack([mate, mate]), jnp.stack([sv, sv]))
+    loops = _loops(jaxpr.jaxpr)
+    (cc,) = [d for prim, d, scope in loops
+             if prim == "scan" and scope.endswith("cc")]
+    (rank,) = [d for prim, d, scope in loops
+               if prim == "scan" and scope.endswith("rank")]
+    assert cc == rank + 1, loops

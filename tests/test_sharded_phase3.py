@@ -111,6 +111,7 @@ def random_cycle_cover(n_vertices, n_trails, trail_len):
     return mate, sv, E
 
 def check(mate, sv, E, n, label):
+    """Parity on one mate; returns the spliced mate (one circuit)."""
     n_stubs = 2 * E
     c_rep, m_rep, ok_rep, r_rep = jax.jit(phase3_device)(
         jnp.asarray(mate), jnp.asarray(sv))
@@ -142,6 +143,7 @@ def check(mate, sv, E, n, label):
     circ_np = circuit_from_mate_np(np.asarray(m_sh))
     assert np.array_equal(np.asarray(c_sh), circ_np.astype(np.int32)), (
         f"{label}: host circuit mismatch")
+    return np.asarray(m_sh), int(r_sh)
 
 for trial in range(4):
     nv = int(rng.integers(2, 9))
@@ -149,7 +151,10 @@ for trial in range(4):
     tl = int(rng.integers(2, 7))
     mate, sv, E = random_cycle_cover(nv, nt, tl)
     for n in (1, 2, 4, 8):
-        check(mate, sv, E, n, f"trial{trial}-P{n}")
+        one, _ = check(mate, sv, E, n, f"trial{trial}-P{n}")
+        # the spliced mate is one circuit: both paths skip the splice
+        _, r_one = check(one, sv, E, n, f"trial{trial}-P{n}-one-cycle")
+        assert r_one == 0, r_one
 print("FUNCTION_PARITY_OK")
 ''', n=8)
     assert "FUNCTION_PARITY_OK" in out
